@@ -1,5 +1,8 @@
 """Shared benchmark utilities: result I/O, subprocess runner for
-multi-device benches (the parent process must keep 1 CPU device)."""
+multi-device benches (the parent process must keep 1 CPU device).
+
+Every timing these benches record is a CPU timing (XLA's CPU backend,
+forced host devices, Pallas in interpret mode), never a device metric."""
 from __future__ import annotations
 
 import json
@@ -22,9 +25,13 @@ def save(name: str, payload) -> str:
 
 
 def run_sub(code: str, devices: int = 4, timeout: int = 540) -> str:
-    """Run ``code`` in a subprocess with ``devices`` forced host devices;
-    returns stdout (the child prints a JSON payload on its last line)."""
+    """Run ``code`` in a subprocess on ``devices`` forced host CPU
+    devices; returns stdout (the child prints a JSON payload on its last
+    line).  The child is pinned to the CPU (``JAX_PLATFORMS=cpu``), so
+    on a machine with a chip it never contends with its parent for the
+    device: what it records is a CPU record."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

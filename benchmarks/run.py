@@ -12,6 +12,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.cache import enable_compile_cache
+
 from . import (bench_kernels, bench_lasso, bench_lda, bench_memory,
                bench_mf, bench_obs, bench_part, bench_pipeline,
                bench_scaling, bench_sched, bench_serve, bench_ssp,
@@ -50,6 +52,7 @@ def main(argv=None) -> None:
             ap.error(f"unknown benchmark name(s) {sorted(unknown)}; "
                      f"valid: {sorted(BENCHES) + ['roofline']}")
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for name, mod in BENCHES.items():

@@ -415,15 +415,16 @@ def build_state(cfg: LDAConfig, words, docs, z0):
     """Materialize consistent D, B, s from the initial assignments."""
     U, Tp, dpw = cfg.num_workers, cfg.tokens_per_worker, cfg.docs_per_worker
     Vp, K = cfg.padded_vocab, cfg.num_topics
+    w = np.asarray(words)[:U * Tp]
+    d = np.asarray(docs)[:U * Tp]
+    k = np.asarray(z0)[:U * Tp]
+    act = w >= 0                                   # skip -1 padding
+    u = np.repeat(np.arange(U), Tp)[act]           # owning worker
     D = np.zeros((U * dpw, K), np.float32)
     B = np.zeros((Vp, K), np.float32)
-    for u in range(U):
-        for i in range(Tp):
-            v, d, k = words[u * Tp + i], docs[u * Tp + i], z0[u * Tp + i]
-            if v < 0:
-                continue
-            D[u * dpw + d, k] += 1
-            B[v, k] += 1
+    # counts stay exact in float32 (every cell < 2**24)
+    np.add.at(D, (u * dpw + d[act], k[act]), 1)
+    np.add.at(B, (w[act], k[act]), 1)
     s = B.sum(axis=0).astype(np.float32)
     return {"z": jnp.asarray(z0), "D": jnp.asarray(D), "B": jnp.asarray(B),
             "s": jnp.asarray(s), "s_err": jnp.float32(0)}

@@ -1,43 +1,32 @@
-"""Version-portable wrappers over the handful of jax APIs that moved.
+"""The two jax entry points every mesh and round body goes through.
 
-The repo targets the modern API surface (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh`` with ``axis_types``) but must also run on
-the jax 0.4.x line, where ``shard_map`` still lives in ``jax.experimental``
-and takes ``check_rep``.  Everything else in the codebase imports the two
-helpers below instead of touching the moving targets directly.
+Everything else in the codebase imports these helpers instead of calling
+``jax.shard_map`` / ``jax.make_mesh`` directly, so the choices below are
+made in one place.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-if hasattr(jax, "shard_map"):                          # jax >= 0.5
-    _shard_map_impl = jax.shard_map
-else:                                                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SM_PARAMS = inspect.signature(_shard_map_impl).parameters
-if "check_vma" in _SM_PARAMS:
-    _SM_CHECK_KW = {"check_vma": False}
-elif "check_rep" in _SM_PARAMS:
-    _SM_CHECK_KW = {"check_rep": False}
-else:                                                  # pragma: no cover
-    _SM_CHECK_KW = {}
+from jax.sharding import AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax version.
+    """``jax.shard_map`` with replication checking off.
 
     Replication checking is disabled uniformly because several round
     bodies mix ``psum``-ed (replicated) and worker-local outputs in one
     pytree, which the static checker cannot always prove consistent.
     """
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **_SM_CHECK_KW)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` without the version-dependent ``axis_types``
-    argument (newer jax defaults every axis to Auto anyway)."""
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    jax's own default makes every axis Explicit, under which gathers and
+    scatters on row-sharded operands (MF/LDA ``query``, streaming
+    ``ingest``, the LM embedding lookup) raise ``ShardingTypeError``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names))

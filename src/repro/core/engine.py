@@ -674,6 +674,14 @@ class StradsEngine:
                                        roles=self.app_roles())
         return self.kvstore.place_tree(state)
 
+    def replicate(self, tree):
+        """Commit a carry pytree (PRNG key, scheduler carry, counters,
+        clocks) replicated over the mesh — the placement every executor
+        returns it in — so a fresh run and a run resumed from a carry
+        hand the compiled program the same input shardings and share
+        one compilation."""
+        return jax.device_put(tree, NamedSharding(self.mesh, P()))
+
     def shard_data(self, data):
         return jax.tree.map(
             lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
@@ -811,9 +819,10 @@ class StradsEngine:
         if num_steps:
             fn = self._get_scan_fn(num_steps, pipeline_depth, collect,
                                    donate, unroll, sched0 is not None)
+            rng, sc, obs = self.replicate((rng, sc, obs))
             args = (state, data, rng, jnp.int32(t0), sc, obs)
             if sched0 is not None:
-                args += (sched0,)
+                args += (self.replicate(sched0),)
             state, rng, sched_c, sc, obs, ys = fn(*args)
             if collect is not None:
                 traces.append(ys)
@@ -1440,8 +1449,11 @@ def single_device_mesh() -> Mesh:
 def worker_mesh(num_workers: int) -> Mesh:
     devs = jax.devices()
     if len(devs) < num_workers:
+        hint = (" (on the CPU, set XLA_FLAGS=--xla_force_host_platform_"
+                "device_count=N before importing jax)"
+                if devs[0].platform == "cpu" else "")
         raise ValueError(
             f"mesh of {num_workers} workers needs ≥{num_workers} devices; "
-            f"have {len(devs)} (set XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count=N before importing jax)")
+            f"found {len(devs)} {devs[0].platform} device(s)"
+            f" ({devs[0].device_kind}){hint}")
     return make_mesh((num_workers,), (DATA_AXIS,))

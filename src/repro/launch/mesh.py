@@ -9,13 +9,15 @@ from __future__ import annotations
 
 import jax
 
+from ..core.compat import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 (data, model) single pod; 2×16×16 (pod, data, model) for the
     two-pod 512-chip deployment."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(devices=None):
@@ -28,4 +30,4 @@ def make_test_mesh(devices=None):
         if n % m == 0:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .cache import enable_compile_cache
+
 ENGINES = ("lasso", "lda", "mf")
 
 
@@ -145,6 +147,7 @@ def main(argv=None):
     ap.add_argument("--out", default="",
                     help="write the JSON artifact (spec/plan embedded)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if not args.stream:
         for flag, name in ((args.stream_kind, "--stream-kind"),
@@ -183,12 +186,10 @@ def main(argv=None):
     if plan.workers is not None and plan.workers != workers:
         raise SystemExit(f"plan.workers={plan.workers} but "
                          f"{workers} requested")
-    if workers > jax.device_count():
-        raise SystemExit(
-            f"{workers} workers want {workers} devices but only "
-            f"{jax.device_count()} are visible (force more with "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    mesh = worker_mesh(workers)
+    try:
+        mesh = worker_mesh(workers)
+    except ValueError as e:
+        raise SystemExit(str(e))
 
     kw = dict(max_batch=args.max_batch,
               batch_window_ms=args.batch_window_ms)

@@ -48,6 +48,7 @@ from ..optim import AdamWConfig, cosine_schedule, wsd_schedule
 from ..sharding.rules import activation_mesh
 from ..train import TrainConfig, make_train_step, init_train_state
 from ..train.step import init_strads_state, make_strads_train_step
+from .cache import enable_compile_cache
 from .mesh import make_test_mesh
 
 
@@ -94,6 +95,7 @@ def main(argv=None):
                          "(0,1] is equivalent; min_distance is the real "
                          "knob)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.plan and (args.scheduler or args.rho is not None):
         ap.error("--scheduler/--rho conflict with --plan (the plan's "

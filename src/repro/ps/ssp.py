@@ -554,8 +554,9 @@ def run_ssp(eng, state, data, rng, num_rounds: int, *, staleness: int = 0,
                 "SSPCarry.sched_carry a previous run returned for a "
                 "bit-exact resume", UserWarning, stacklevel=2)
     fn, info = _get_ssp_fn(eng, num_steps, staleness, collect, donate)
-    state, carry, telem, ys = fn(state, data, rng,
-                                 jnp.int32(t0), jnp.asarray(clocks),
+    rng, clocks, sched_carry0, obs0 = eng.replicate(
+        (rng, jnp.asarray(clocks), sched_carry0, obs0))
+    state, carry, telem, ys = fn(state, data, rng, jnp.int32(t0), clocks,
                                  sched_carry0, obs0)
 
     ret = [state]

@@ -169,3 +169,33 @@ def test_block_norms():
     block_of = {"a": 0, "b": 1}
     n = np.asarray(block_norms(updates, block_of, 2))
     assert np.isclose(n[0], 6.0) and np.isclose(n[1], 6.0)
+
+
+# ---------------------------------------------------------------------------
+# Meshes: every axis Auto, so gathers on row-sharded state trace
+# ---------------------------------------------------------------------------
+
+def test_meshes_carry_auto_axes_and_mf_query_gathers():
+    from jax.sharding import AxisType
+
+    from repro.apps import mf
+    from repro.core import single_device_mesh, worker_mesh
+    from repro.launch.mesh import make_test_mesh
+
+    mesh = single_device_mesh()
+    assert mesh.axis_types == (AxisType.Auto,)
+    assert worker_mesh(1).axis_types == (AxisType.Auto,)
+    assert set(make_test_mesh().axis_types) == {AxisType.Auto}
+    # MF recommend gathers rows of the row-sharded W (Explicit axes
+    # raised ShardingTypeError here)
+    cfg = mf.MFConfig(num_rows=8, num_cols=6, rank=2, top_k=3)
+    A, mask = mf.synthetic_ratings(np.random.default_rng(0), 8, 6, 2)
+    eng = mf.make_engine(cfg, mesh)
+    state = eng.init_state(jax.random.key(0), A=jnp.asarray(A),
+                           mask=jnp.asarray(mask))
+    out = jax.jit(eng.app.query)(state,
+                                 {"user": jnp.array([0, 5], jnp.int32)})
+    assert out["items"].shape == (2, 3)
+    want = np.argsort(-(np.asarray(state["W"])[[0, 5]]
+                        @ np.asarray(state["H"])), axis=1)[:, :3]
+    assert (np.asarray(out["items"]) == want).all()

@@ -58,8 +58,9 @@ def test_hlo_analyzer_loop_and_collectives():
     out = run_sub("""
         import jax, jax.numpy as jnp, json
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.core.compat import make_mesh
         from repro.launch.roofline import analyze_hlo
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         L, M, K, N = 7, 64, 128, 256
         def f(x, w):
             def body(i, acc):
@@ -190,3 +191,37 @@ def test_sharded_train_step_matches_single_device():
     res = json.loads(out.strip().splitlines()[-1])
     assert res["l1"] == pytest.approx(res["l2"], rel=2e-3)
     assert res["g1"] == pytest.approx(res["g2"], rel=2e-2)
+
+
+# ---------------------------------------------------------------------------
+# compile cache placement
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_env_dir_stands_else_fixed_checkout_path(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is where compiles land and
+    nothing else is written; without it they land in <root>/.jax_cache."""
+    code = textwrap.dedent(f"""
+        import jax, jax.numpy as jnp
+        from repro.launch.cache import enable_compile_cache
+        print(enable_compile_cache({str(tmp_path / "root")!r}))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.jit(lambda x: jnp.sin(x) @ x)(jnp.ones((32, 32))).block_until_ready()
+    """)
+    env_dir = tmp_path / "env"
+    for extra in ({"JAX_COMPILATION_CACHE_DIR": str(env_dir)}, {}):
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env.update(extra, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.path.join(ROOT, "src"))
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True, env=env,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr[-4000:]
+        if extra:
+            assert out.stdout.split()[-1] == str(env_dir)
+            assert any(env_dir.iterdir())
+            assert not (tmp_path / "root").exists()
+        else:
+            assert out.stdout.split()[-1] == str(tmp_path / "root"
+                                                 / ".jax_cache")
+            assert any((tmp_path / "root" / ".jax_cache").iterdir())

@@ -64,6 +64,28 @@ def test_baseline_runs_and_improves(mesh, setup):
     assert trace[-1][1] > trace[0][1]
 
 
+def test_build_state_matches_token_loop():
+    """The vectorised counts equal a per-token loop's, -1 padding
+    skipped, on several workers."""
+    r = np.random.default_rng(1)
+    cfg = lda.LDAConfig(vocab=40, num_topics=5, num_workers=3,
+                        tokens_per_worker=200, docs_per_worker=7)
+    words, docs, z0 = lda.synthetic_corpus(r, cfg, true_topics=4)
+    words[r.random(words.shape) < 0.1] = -1
+    U, Tp, dpw = cfg.num_workers, cfg.tokens_per_worker, cfg.docs_per_worker
+    D = np.zeros((U * dpw, cfg.num_topics), np.float32)
+    B = np.zeros((cfg.padded_vocab, cfg.num_topics), np.float32)
+    for i in range(U * Tp):
+        if words[i] >= 0:
+            D[(i // Tp) * dpw + docs[i], z0[i]] += 1
+            B[words[i], z0[i]] += 1
+    got = lda.build_state(cfg, words, docs, z0)
+    assert np.array_equal(np.asarray(got["D"]), D)
+    assert np.array_equal(np.asarray(got["B"]), B)
+    assert np.array_equal(np.asarray(got["s"]), B.sum(0))
+    assert np.array_equal(np.asarray(got["z"]), z0)
+
+
 def test_block_partition_covers_vocab():
     cfg = lda.LDAConfig(vocab=53, num_topics=4, num_workers=4,
                         tokens_per_worker=10, docs_per_worker=2)

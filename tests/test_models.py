@@ -14,6 +14,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import ARCHS, get_config
+from repro.core.compat import make_mesh
 from repro.kernels import ref
 from repro.models import model as M
 from repro.models.layers import _chunked_attention, _sdpa_grouped
@@ -273,7 +274,7 @@ def test_padded_vocab_is_shardable():
 
 
 def test_resolve_drops_nondivisible():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # 1-way mesh: everything divides, spec resolves without error
     spec = rules.resolve(mesh, (rules.BATCH, rules.TENSOR), (4, 6))
     assert spec is not None
