@@ -160,7 +160,8 @@ class StradsLDA(StradsAppBase):
         # the injected rotation policy owns the block↔worker assignment
         # and the (static) ppermute communication pattern it implies
         p_fwd = self.scheduler.forward_perm(phase)         # block → worker
-        B = jax.lax.ppermute(state["B"], "data", p_fwd)
+        with jax.named_scope("exchange"):
+            B = jax.lax.ppermute(state["B"], "data", p_fwd)
 
         p = jax.lax.axis_index("data")
         block = self.scheduler.block_for_worker(p, phase)
@@ -177,13 +178,28 @@ class StradsLDA(StradsAppBase):
 
         # send the processed block home
         p_bwd = self.scheduler.backward_perm(phase)
-        B_home = jax.lax.ppermute(B, "data", p_bwd)
+        with jax.named_scope("exchange"):
+            B_home = jax.lax.ppermute(B, "data", p_bwd)
 
         # partials for pull: fresh column sums + s-error numerator
         s_partial = jnp.sum(B, axis=0)                    # this block's sums
         partial = {"s": s_partial}
         local = {"z": z_new, "D": D, "B": B_home, "s_tilde": s_tilde}
         return partial, local
+
+    def obs_counts(self, data, sched, phase):
+        """(visited, updated) of one round, for the device counters: the
+        valid tokens every worker's Gibbs scan steps over, and those it
+        samples (its word in the worker's current block).  Reads only the
+        data and the rotation."""
+        cfg = self.cfg
+        words = data["words"].reshape(cfg.num_workers, -1)
+        block = self.scheduler.block_for_worker(
+            jnp.arange(cfg.num_workers), phase)
+        valid = words >= 0
+        active = valid & (words // cfg.block_vocab == block[:, None])
+        return (jnp.sum(valid, dtype=jnp.int32),
+                jnp.sum(active, dtype=jnp.int32))
 
     def pull(self, state, sched, z, local, data, phase):
         cfg = self.cfg

@@ -100,7 +100,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels import KernelSpec, build_kernels
 from ..obs import RunReport, counters as obs_counters
-from ..obs.events import Recorder
+from ..obs.events import Recorder, active, recording
 from ..part import Assignment, PartitionerSpec, build_partitioner
 from ..sched import SchedulerSpec, build_scheduler
 from .compat import make_mesh, shard_map
@@ -184,7 +184,6 @@ class StradsEngine:
         self.partitioner = None
         self._assignment: Optional[Assignment] = None
         self._part_stats = None
-        self._recorder: Optional[Recorder] = None   # live during execute
         self.set_kernels(None)    # before set_scheduler's first round-bind
         self.set_scheduler(None)
         self.set_partitioner(None)
@@ -193,18 +192,30 @@ class StradsEngine:
     # -- observability hooks (the telemetry-injection contract) --------------
 
     def _obs_event(self, name: str, **args):
-        """Record a host event when a Recorder is live (``kind="trace"``
-        during ``execute``) — a no-op otherwise, so event sites cost
-        nothing uninstrumented."""
-        if self._recorder is not None:
-            self._recorder.instant(name, **args)
+        """Record a host event into the active Recorder (one made active
+        for the run, or by a ``kind="trace"`` ``execute``) — a no-op
+        otherwise, so event sites cost nothing uninstrumented."""
+        rec = active()
+        if rec is not None:
+            rec.instant(name, **args)
 
     def _obs_span(self, name: str, **args):
-        """A wall-clock phase span under a live Recorder, else a
+        """A wall-clock phase span in the active Recorder, else a
         null context."""
-        if self._recorder is not None:
-            return self._recorder.span(name, **args)
+        rec = active()
+        if rec is not None:
+            return rec.span(name, **args)
         return _NULL_CTX
+
+    def _observe(self, obs, data, sched, phase: int, num_cand: int):
+        """Fold one round into the device counters, with the app's
+        ``obs_counts(data, sched, phase)`` (visited, updated) where it
+        has the hook — it reads only the data and the schedule, so the
+        instrumented round stays bit-identical."""
+        hook = getattr(self.app, "obs_counts", None)
+        counts = hook(data, sched, phase) if hook is not None else None
+        return obs_counters.observe_round(obs, sched, phase, num_cand,
+                                          counts)
 
     def _obs_num_candidates(self) -> int:
         """The active scheduler's static proposal-pool size U′ (0 for
@@ -601,20 +612,23 @@ class StradsEngine:
         """propose → [schedule_stats → psum] → schedule (replicated)."""
         app = self.app
         r1, r2 = jax.random.split(rng)
-        cand = app.propose(state, carry, r1, t, phase)
+        with jax.named_scope("propose"):
+            cand = app.propose(state, carry, r1, t, phase)
         if self._needs_stats:
             def stats_fn(data, state, cand):
                 s = app.schedule_stats(data, state, cand, phase)
                 return tree_psum(s, DATA_AXIS)
-            stats = shard_map(
-                stats_fn, mesh=self.mesh,
-                in_specs=(self.data_specs, self._sspec(state),
-                          _replicate_spec(cand)),
-                out_specs=P(),
-            )(data, state, cand)
+            with jax.named_scope("schedule_stats"):
+                stats = shard_map(
+                    stats_fn, mesh=self.mesh,
+                    in_specs=(self.data_specs, self._sspec(state),
+                              _replicate_spec(cand)),
+                    out_specs=P(),
+                )(data, state, cand)
         else:
             stats = None
-        return app.schedule(state, carry, cand, stats, r2, t, phase)
+        with jax.named_scope("schedule"):
+            return app.schedule(state, carry, cand, stats, r2, t, phase)
 
     def _apply(self, state, data, sched, phase):
         """push → psum → pull under shard_map (the BSP update + sync)."""
@@ -622,9 +636,12 @@ class StradsEngine:
         sspec = self._sspec(state)
 
         def push_pull(data, state, sched):
-            z, local = app.push(data, state, sched, phase)
-            z = tree_psum(z, DATA_AXIS)      # pull aggregation Σ_p z^p
-            return app.pull(state, sched, z, local, data, phase)
+            with jax.named_scope("push"):
+                z, local = app.push(data, state, sched, phase)
+            with jax.named_scope("aggregate"):
+                z = tree_psum(z, DATA_AXIS)  # pull aggregation Σ_p z^p
+            with jax.named_scope("pull"):
+                return app.pull(state, sched, z, local, data, phase)
 
         return shard_map(
             push_pull, mesh=self.mesh,
@@ -634,7 +651,10 @@ class StradsEngine:
 
     def _sched_update(self, carry, before, after, sched, phase):
         fn = getattr(self.app, "sched_update", None)
-        return fn(carry, before, after, sched, phase) if fn else carry
+        if fn is None:
+            return carry
+        with jax.named_scope("sched_update"):
+            return fn(carry, before, after, sched, phase)
 
     def _build_round(self):
         @partial(jax.jit, static_argnums=(4,))
@@ -654,7 +674,10 @@ class StradsEngine:
         """Initialize the app state and place it through the KV store
         (extra keyword args go to ``app.init_state`` — e.g. the Lasso
         residual seed ``y``)."""
-        return self.place_state(self.app.init_state(rng, **app_kwargs))
+        with self._obs_span("init_state"):
+            with self._obs_span("app.init_state"):
+                state = self.app.init_state(rng, **app_kwargs)
+            return self.place_state(state)
 
     def app_roles(self) -> dict:
         """The app's declarative VarSpec role map (``var_roles()``; see
@@ -670,9 +693,11 @@ class StradsEngine:
         (``self.kvstore`` afterwards answers Fig-3-style questions like
         ``bytes_per_device()``, and ``repro.ps`` derives the server-/
         worker-resident split from the same VarSpecs)."""
-        self.kvstore = store_from_tree(self.mesh, state, self._sspec(state),
-                                       roles=self.app_roles())
-        return self.kvstore.place_tree(state)
+        with self._obs_span("place_state"):
+            self.kvstore = store_from_tree(self.mesh, state,
+                                           self._sspec(state),
+                                           roles=self.app_roles())
+            return self.kvstore.place_tree(state)
 
     def replicate(self, tree):
         """Commit a carry pytree (PRNG key, scheduler carry, counters,
@@ -683,9 +708,10 @@ class StradsEngine:
         return jax.device_put(tree, NamedSharding(self.mesh, P()))
 
     def shard_data(self, data):
-        return jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
-            data, self.data_specs)
+        with self._obs_span("shard_data"):
+            return jax.tree.map(
+                lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
+                data, self.data_specs)
 
     # -- execution: host loop ------------------------------------------------
 
@@ -817,13 +843,16 @@ class StradsEngine:
         sc = sched_carry0
         obs = obs0
         if num_steps:
-            fn = self._get_scan_fn(num_steps, pipeline_depth, collect,
-                                   donate, unroll, sched0 is not None)
-            rng, sc, obs = self.replicate((rng, sc, obs))
-            args = (state, data, rng, jnp.int32(t0), sc, obs)
-            if sched0 is not None:
-                args += (self.replicate(sched0),)
-            state, rng, sched_c, sc, obs, ys = fn(*args)
+            with self._obs_span("scan.program"):
+                fn = self._get_scan_fn(num_steps, pipeline_depth, collect,
+                                       donate, unroll, sched0 is not None)
+            with self._obs_span("scan.replicate"):
+                rng, sc, obs = self.replicate((rng, sc, obs))
+                args = (state, data, rng, jnp.int32(t0), sc, obs)
+                if sched0 is not None:
+                    args += (self.replicate(sched0),)
+            with self._obs_span("scan.call"):
+                state, rng, sched_c, sc, obs, ys = fn(*args)
             if collect is not None:
                 traces.append(ys)
 
@@ -834,10 +863,10 @@ class StradsEngine:
             t = t0 + num_steps * L + k
             rng, sub = jax.random.split(rng)
             out = self.run_round(state, data, sub, t, sched_carry=sc)
-            state, sc = out.state, out.sched_carry
             if obs is not None:
-                obs = obs_counters.observe_round(obs, out.sched,
-                                                 t % period, num_cand)
+                obs = self._observe(obs, data, out.sched, t % period,
+                                    num_cand)
+            state, sc = out.state, out.sched_carry
             if collect is not None:
                 traces.append(jax.tree.map(
                     lambda x: jnp.asarray(x)[None], collect(state)))
@@ -966,6 +995,57 @@ class StradsEngine:
             raise TypeError(f"execute() wants an ExecutionPlan; got "
                             f"{type(plan).__name__} (legacy executor= "
                             f"kwargs live behind the app-level fit shims)")
+        # telemetry (the telemetry-injection contract): the resolved
+        # TelemetrySpec turns on device counters for every executor;
+        # kind="trace" records this call's spans and events (cache
+        # misses, rebalances, checkpoints, compiles, collections) into
+        # the active Recorder, or into one of its own made active for the
+        # call.  The final report's .telemetry is a uniform RunReport,
+        # built without waiting for the device.
+        tspec = plan.telemetry or None
+        own = (Recorder(profiler=tspec.profiler)
+               if tspec is not None and tspec.events and active() is None
+               else None)
+        with recording(own) if own is not None else _NULL_CTX:
+            rec = active()
+            mark = rec.mark() if rec is not None else 0
+            with self._obs_span("execute", executor=plan.executor,
+                                rounds=plan.rounds):
+                with self._obs_span("execute.resolve"):
+                    t_done, rng, chunk, pspec, ingestor = self._resolve(
+                        plan, rng, carry, callback, ckpt_dir, partition,
+                        stream, source, stream_state, data)
+                rep = self._execute_plan(state, data, rng, plan, t_done,
+                                         carry, collect, callback, chunk,
+                                         pspec, ckpt_dir, ingestor)
+            events = (rec.events_since(mark)
+                      if tspec is not None and tspec.events else [])
+        if tspec is None:
+            rep.telemetry = None
+            return rep
+        ssp_parts = rep.telemetry if isinstance(rep.telemetry, list) \
+            else ([rep.telemetry] if rep.telemetry is not None else [])
+        if len(ssp_parts) > 1:
+            from ..ps.telemetry import merge_summaries
+            ssp = merge_summaries(ssp_parts)
+        else:
+            ssp = ssp_parts[0] if ssp_parts else None
+        # the host's round index: every executor runs the plan to its end
+        # but a host loop a callback stopped (whose carry.t is a host int)
+        rounds = (int(rep.carry.t) if callback is not None
+                  else plan.rounds)
+        rep.telemetry = RunReport.build(
+            tspec, plan.executor, rounds,
+            device_counters=getattr(rep.carry, "obs", None), ssp=ssp,
+            events=events)
+        return rep
+
+    def _resolve(self, plan: ExecutionPlan, rng, carry, callback, ckpt_dir,
+                 partition, stream, source, stream_state, data):
+        """``execute``'s checks and policy resolution before any round
+        runs: the set_* calls, the carry checks, the checkpoint and
+        stream arguments.  Returns ``(t_done, rng, chunk, pspec,
+        ingestor)``."""
         num_workers = self.mesh.shape[DATA_AXIS]
         if plan.workers is not None and plan.workers != num_workers:
             raise ValueError(
@@ -1048,40 +1128,7 @@ class StradsEngine:
                 f"must be a multiple of plan.checkpoint_every={chunk} — "
                 f"repartition checks only run at chunk boundaries, so a "
                 f"misaligned cadence would silently (almost) never fire")
-        # telemetry (the telemetry-injection contract): the resolved
-        # TelemetrySpec turns on device counters for every executor;
-        # kind="trace" additionally opens a host Recorder for the span
-        # of this execute (cache misses, rebalances, checkpoints, phase
-        # spans).  The final report's .telemetry is a uniform RunReport.
-        tspec = plan.telemetry or None
-        rec = (Recorder(profiler=tspec.profiler)
-               if tspec is not None and tspec.events else None)
-        self._recorder = rec
-        try:
-            with (rec.span("execute", executor=plan.executor,
-                           rounds=plan.rounds) if rec is not None
-                  else _NULL_CTX):
-                rep = self._execute_plan(state, data, rng, plan, t_done,
-                                         carry, collect, callback, chunk,
-                                         pspec, ckpt_dir, ingestor)
-        finally:
-            self._recorder = None
-        if tspec is not None:
-            ssp_parts = rep.telemetry if isinstance(rep.telemetry, list) \
-                else ([rep.telemetry] if rep.telemetry is not None
-                      else [])
-            if len(ssp_parts) > 1:
-                from ..ps.telemetry import merge_summaries
-                ssp = merge_summaries(ssp_parts)
-            else:
-                ssp = ssp_parts[0] if ssp_parts else None
-            rep.telemetry = RunReport.build(
-                tspec, plan.executor, int(rep.carry.t),
-                device_counters=getattr(rep.carry, "obs", None),
-                recorder=rec, ssp=ssp)
-        else:
-            rep.telemetry = None
-        return rep
+        return t_done, rng, chunk, pspec, ingestor
 
     def _execute_plan(self, state, data, rng, plan: ExecutionPlan,
                       t_done: int, carry, collect, callback, chunk: int,
@@ -1241,8 +1288,8 @@ class StradsEngine:
                                          sched_carry=sc)
                     state, sc = out.state, out.sched_carry
                     if obs is not None:
-                        obs = obs_counters.observe_round(
-                            obs, out.sched, t % period, num_cand)
+                        obs = self._observe(obs, data, out.sched,
+                                            t % period, num_cand)
                     executed = k + 1
                     if cfn is not None:
                         ys.append(cfn(state))
@@ -1320,8 +1367,7 @@ class StradsEngine:
             # op/PRNG order of the host-loop round.
             sched = self._make_schedule(state, sc, data, rng, t, phase)
             if obs is not None:
-                obs = obs_counters.observe_round(obs, sched, phase,
-                                                 num_cand)
+                obs = self._observe(obs, data, sched, phase, num_cand)
             new_state = self._apply(state, data, sched, phase)
             sc = self._sched_update(sc, state, new_state, sched, phase)
             if collect is not None:
@@ -1369,8 +1415,8 @@ class StradsEngine:
                         if obs is not None:
                             # count the schedule the round EXECUTES (the
                             # one-round-stale one), not the prefetch
-                            obs = obs_counters.observe_round(
-                                obs, sched, i % period, num_cand)
+                            obs = self._observe(obs, data, sched,
+                                                i % period, num_cand)
                         new_state = self._apply(state, data, sched,
                                                 i % period)
                         sc = self._sched_update(sc, state, new_state,

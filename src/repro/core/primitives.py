@@ -149,29 +149,44 @@ Observability follows the same declarative shape: a
 :class:`~repro.core.plan.ExecutionPlan` (``plan.telemetry``; the legacy
 boolean form still parses — ``True`` means ``kind="counters"`` with a
 ``DeprecationWarning``).  Unlike the scheduler/partitioner/kernel
-contracts, apps implement **nothing**: instrumentation is engine-owned
-and rides *outside* the primitives, so it can never change what a round
-computes.
+contracts, instrumentation is engine-owned and rides *outside* the
+primitives, so it can never change what a round computes; an app may
+add one optional hook.
 
 * **Device counters** (any spec) are an extra pytree leaf threaded
   through every executor's carry (``EngineCarry.obs`` /
   ``SSPCarry.obs``; ``None`` when telemetry is off, so old checkpoints
   restore unchanged).  Counters are derived *only* from the already-
-  computed schedule pytree — per-phase round counts, scheduled-block
-  widths, and the ρ-filter ledger (``proposed = accepted + killed``
-  from the keep-mask popcounts) — never from model state or the PRNG
-  stream, which is what makes the instrumented run **bit-identical**
-  to the uninstrumented one.
-* **Host events** (``kind="trace"``) come from an engine-owned
-  ``Recorder``: executor/chunk/checkpoint spans, compiled-program
-  cache misses keyed by the (SchedulerSpec, Assignment, KernelSpec)
-  triple, and rebalance decisions — all recorded at host phase
-  boundaries, never inside a traced program.
+  computed schedule pytree and the data — per-phase round counts, the
+  ρ-filter ledger (``proposed = accepted + killed`` from the keep-mask
+  popcounts), and the optional app hook ``obs_counts(data, sched,
+  phase) -> (visited, updated)`` (the items a round's push steps over
+  and those it updates) — never from model state or the PRNG stream,
+  which is what makes the instrumented run **bit-identical** to the
+  uninstrumented one.
+* **Host events** (``kind="trace"``) go to the active
+  :class:`~repro.obs.events.Recorder` (one made active for a whole run
+  with :func:`repro.obs.recording`, else one of the call's own):
+  ``execute`` > ``execute.resolve`` and the executor span >
+  ``scan.program``/``scan.replicate``/``scan.call``, chunk/checkpoint
+  spans, JAX's ``jit.*`` compile spans and ``gc`` collections,
+  compiled-program cache misses keyed by the (SchedulerSpec,
+  Assignment, KernelSpec) triple, and rebalance decisions — all
+  recorded at host phase boundaries, never inside a traced program.
+  With a Recorder active, ``init_state`` and ``shard_data`` record
+  spans too, whatever the plan.
+* **Phase scopes**: the round body runs its phases under
+  ``jax.named_scope`` (``repro.obs.PHASES``: propose, schedule_stats,
+  schedule, push, aggregate, pull, sched_update; apps add ``exchange``
+  around a push's collectives), so the compiled program's ``op_name``
+  metadata names the phase of every instruction.  Metadata only: the
+  scopes change no computation.
 * Every ``execute()`` returns the resolved telemetry as a uniform
   :class:`~repro.obs.report.RunReport` in
   ``ExecutionReport.telemetry`` (the SSP staleness summary becomes its
-  ``.ssp`` section); ``repro.launch.trace`` validates and re-exports
-  saved reports offline.
+  ``.ssp`` section), built without waiting for the device;
+  ``repro.launch.trace`` validates and re-exports saved reports
+  offline.
 
 The serving-injection contract
 ------------------------------

@@ -83,6 +83,7 @@ def lasso_partial(Xb: jax.Array, r: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, U), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, U), jnp.float32),
+        name="lasso_partial",
         scratch_shapes=[pltpu.VMEM((1, U), jnp.float32)],
         interpret=interpret,
     )(r.reshape(1, padded), Xb)
@@ -124,4 +125,5 @@ def gram_block(Xc: jax.Array, block_n: int = DEFAULT_BLOCK_N,
         out_shape=jax.ShapeDtypeStruct((U, U), jnp.float32),
         scratch_shapes=[pltpu.VMEM((U, U), jnp.float32)],
         interpret=interpret,
+        name="gram_block",
     )(Xc)

@@ -15,8 +15,9 @@ report's :meth:`~repro.obs.report.RunReport.summary` and, with
 * the file parses and the spec round-trips
   (:func:`~repro.obs.report.report_from_json`);
 * the device-counter identities hold — per-phase round totals sum to
-  the run's rounds and the ρ-filter ledger balances
-  (``accepted + killed == proposed``, all non-negative);
+  the run's rounds, the ρ-filter ledger balances
+  (``accepted + killed == proposed``, all non-negative) and no more
+  items are updated than visited;
 * the host event log is strictly nested with non-negative durations
   (:func:`~repro.obs.events.validate_spans`) — exactly what a Chrome
   trace viewer needs to render it as a flame graph.
@@ -58,8 +59,8 @@ def check_report(rep: RunReport) -> Optional[str]:
     span-nesting contract, else the first violated clause."""
     c = rep.counters
     if c:
-        for k in ("rounds", "sched_size", "proposed", "accepted",
-                  "killed"):
+        for k in ("rounds", "proposed", "accepted", "killed",
+                  "visited", "updated"):
             if c.get(k, 0) < 0:
                 return f"counter {k!r} is negative ({c[k]})"
         if sum(c.get("rounds_per_phase", [])) != c.get("rounds", 0):
@@ -70,6 +71,9 @@ def check_report(rep: RunReport) -> Optional[str]:
             return (f"rho-filter ledger unbalanced: accepted "
                     f"{c['accepted']} + killed {c['killed']} != proposed "
                     f"{c['proposed']}")
+        if c.get("updated", 0) > c.get("visited", 0):
+            return (f"updated {c['updated']} exceeds visited "
+                    f"{c['visited']}")
     err = validate_spans(rep.events)
     if err is not None:
         return err

@@ -10,21 +10,23 @@ contract, :mod:`repro.core.primitives`):
   model state;
 * :mod:`repro.obs.events` — the host-side :class:`Recorder` of typed
   instants and strictly nested wall-clock spans, exportable as JSONL
-  and Chrome-trace files;
+  and Chrome-trace files, for one ``execute`` or, made active by
+  :func:`recording`, for a whole run;
 * :mod:`repro.obs.report` — :class:`RunReport`, the uniform
   ``ExecutionReport.telemetry`` object every executor returns
   (``python -m repro.launch.trace`` summarizes/checks saved ones).
 """
 from .counters import (init_counters, observe_read, observe_round,
                        staleness_init, summarize_counters)
-from .events import (Recorder, chrome_trace, validate_spans,
-                     write_chrome_trace, write_jsonl)
+from .events import (PHASES, Recorder, active, chrome_trace, recording,
+                     validate_spans, write_chrome_trace, write_jsonl)
 from .report import RunReport, report_from_json
 from .spec import TELEMETRY_KINDS, TelemetrySpec
 
 __all__ = [
-    "TELEMETRY_KINDS", "TelemetrySpec", "Recorder", "RunReport",
-    "chrome_trace", "init_counters", "observe_read", "observe_round",
-    "report_from_json", "staleness_init", "summarize_counters",
-    "validate_spans", "write_chrome_trace", "write_jsonl",
+    "PHASES", "TELEMETRY_KINDS", "TelemetrySpec", "Recorder", "RunReport",
+    "active", "chrome_trace", "init_counters", "observe_read",
+    "observe_round", "recording", "report_from_json", "staleness_init",
+    "summarize_counters", "validate_spans", "write_chrome_trace",
+    "write_jsonl",
 ]

@@ -12,15 +12,21 @@ Counters
 ``rounds``     (phase_period,) — rounds executed per static phase; the
                total must equal the rounds the plan ran (the hypothesis
                invariant: ``Σ rounds == R``).
-``sched_size`` scheduled entries actually admitted across the run (for
-               masked schedules the mask popcount; for dense schedules
-               the static schedule width).
 ``proposed``/``accepted``/``killed``
                the ρ-dependency-filter ledger (paper §3.3): candidates
                the scheduler proposed (U′ per round for the dynamic
-               kinds), survivors of the dependency filter, and filtered
-               casualties — ``accepted + killed == proposed`` by
-               construction, and the property test keeps it that way.
+               kinds), survivors of the dependency filter (for masked
+               schedules the mask popcount; for dense schedules the
+               static schedule width), and filtered casualties —
+               ``accepted + killed == proposed`` by construction, and the
+               property test keeps it that way.
+``visited``/``updated``
+               from the app's optional ``obs_counts(data, sched, phase)``
+               hook, which reads only the data and the schedule: the
+               items a round's push steps over and those it updates (LDA:
+               valid tokens scanned, tokens sampled).  Each is a
+               two-word count (``[high, low]``, low < 2**30) so it cannot
+               wrap on a long run; 0 for apps without the hook.
 
 The SSP staleness histogram (``staleness_init``/``observe_read``) lives
 here too — it is the same pattern (an int32 pytree in the scan carry,
@@ -37,19 +43,30 @@ import jax.numpy as jnp
 import numpy as np
 
 
+#: the low word of a two-word count holds this much
+_LOW = 1 << 30
+
+
 def init_counters(phase_period: int) -> Dict[str, jnp.ndarray]:
     """A fresh counter pytree for an app whose phases cycle with period
     ``phase_period`` (1 = phaseless)."""
     return {"rounds": jnp.zeros((phase_period,), jnp.int32),
-            "sched_size": jnp.int32(0),
             "proposed": jnp.int32(0),
             "accepted": jnp.int32(0),
-            "killed": jnp.int32(0)}
+            "killed": jnp.int32(0),
+            "visited": jnp.zeros((2,), jnp.int32),
+            "updated": jnp.zeros((2,), jnp.int32)}
+
+
+def _add_wide(count, x):
+    """``count`` (``[high, low]``) plus ``x`` (< 2**30)."""
+    low = count[1] + jnp.asarray(x, jnp.int32)
+    return jnp.stack([count[0] + low // _LOW, low % _LOW])
 
 
 def observe_round(counters: Dict[str, jnp.ndarray], sched: Any,
-                  phase: int,
-                  num_candidates: int = 0) -> Dict[str, jnp.ndarray]:
+                  phase: int, num_candidates: int = 0,
+                  counts: Optional[tuple] = None) -> Dict[str, jnp.ndarray]:
     """Fold one executed round's schedule into the counters (traced —
     runs inside the executor's scan).
 
@@ -58,9 +75,9 @@ def observe_round(counters: Dict[str, jnp.ndarray], sched: Any,
     accepted count, ``num_candidates`` (the scheduler's static U′; 0 for
     policies without a proposal pool) the proposed count, and the
     difference the killed count.  Schedules without masks (rotation,
-    dense MF rank blocks) contribute their static width to
-    ``sched_size`` and keep the filter ledger balanced with
-    ``proposed == accepted``.
+    dense MF rank blocks) contribute their static width and keep the
+    filter ledger balanced with ``proposed == accepted``.  ``counts`` is
+    the app hook's ``(visited, updated)`` for the round, if it has one.
     """
     c = dict(counters)
     c["rounds"] = c["rounds"].at[phase].add(1)
@@ -70,32 +87,39 @@ def observe_round(counters: Dict[str, jnp.ndarray], sched: Any,
     if masks:
         acc = sum(jnp.sum(m.astype(jnp.int32)) for m in masks)
         prop = (jnp.int32(num_candidates) if num_candidates else acc)
-        c["sched_size"] = c["sched_size"] + acc
         c["accepted"] = c["accepted"] + acc
         c["proposed"] = c["proposed"] + prop
         c["killed"] = c["killed"] + (prop - acc)
     else:
         width = int(sum(np.prod(jnp.shape(x), dtype=int)
                         for x in leaves))
-        c["sched_size"] = c["sched_size"] + jnp.int32(width)
         # no filter ran: the ledger stays balanced at proposed==accepted
         c["proposed"] = c["proposed"] + jnp.int32(width)
         c["accepted"] = c["accepted"] + jnp.int32(width)
+    if counts is not None:
+        c["visited"] = _add_wide(c["visited"], counts[0])
+        c["updated"] = _add_wide(c["updated"], counts[1])
     return c
+
+
+def _wide(count) -> int:
+    high, low = (int(v) for v in np.asarray(count))
+    return high * _LOW + low
 
 
 def summarize_counters(counters: Optional[Dict[str, Any]]) -> dict:
     """Host ints out of the device counter pytree (empty dict for an
-    uninstrumented run)."""
+    uninstrumented run).  This reads the device: it waits for the run."""
     if counters is None:
         return {}
     per_phase = [int(v) for v in np.asarray(counters["rounds"])]
     return {"rounds": int(sum(per_phase)),
             "rounds_per_phase": per_phase,
-            "sched_size": int(counters["sched_size"]),
             "proposed": int(counters["proposed"]),
             "accepted": int(counters["accepted"]),
-            "killed": int(counters["killed"])}
+            "killed": int(counters["killed"]),
+            "visited": _wide(counters["visited"]),
+            "updated": _wide(counters["updated"])}
 
 
 # ---------------------------------------------------------------------------

@@ -2,10 +2,10 @@
 
 ``ExecutionReport.telemetry`` is one of these for **every** executor
 whenever the plan carries a :class:`~repro.obs.spec.TelemetrySpec`:
-the resolved spec, the device counters summarized to host ints, the
-host event log (``kind="trace"``), and — for SSP runs — the
-:class:`~repro.ps.telemetry.SSPTelemetry` staleness/byte section that
-used to be the whole telemetry story.
+the resolved spec, the device counters (summarized to host ints when
+first read), the host event log (``kind="trace"``), and — for SSP runs —
+the :class:`~repro.ps.telemetry.SSPTelemetry` staleness/byte section
+that used to be the whole telemetry story.
 
 A RunReport is JSON-first: ``to_json()`` is what dryrun/train/benchmark
 artifacts embed and what ``python -m repro.launch.trace`` summarizes,
@@ -25,39 +25,58 @@ from .spec import TelemetrySpec
 class RunReport:
     """One instrumented run, summarized uniformly across executors.
 
+    Building one never waits for the device: the counters stay device
+    arrays until first read (:attr:`counters`, :meth:`summary`,
+    :meth:`to_json`), so an instrumented ``execute`` returns while its
+    rounds still run, as an uninstrumented one does.
+
     spec:      the resolved :class:`TelemetrySpec` that instrumented
                the run.
     executor:  the plan's executor name.
-    rounds:    rounds the plan executed.
-    counters:  host-int summary of the device counter pytree (see
-               :func:`repro.obs.counters.summarize_counters`); ``{}``
-               only for runs that executed zero rounds.
+    rounds:    the host's round index after the run.
     events:    the host event log (instants + strictly nested spans,
                microsecond timestamps) — ``[]`` under
                ``kind="counters"``.
     ssp:       the :class:`repro.ps.telemetry.SSPTelemetry` section
                (staleness histogram + byte accounting); ``None`` for
                the BSP executors.
+    device_counters:
+               the device counter pytree off the final carry, until
+               :attr:`counters` first reads it.
     """
     spec: TelemetrySpec
     executor: str
     rounds: int
-    counters: dict = dataclasses.field(default_factory=dict)
     events: List[dict] = dataclasses.field(default_factory=list)
     ssp: Any = None
+    device_counters: Any = dataclasses.field(default=None, repr=False)
+    _counters: Optional[dict] = dataclasses.field(default=None,
+                                                  repr=False)
+
+    @property
+    def counters(self) -> dict:
+        """Host-int summary of the device counters (see
+        :func:`repro.obs.counters.summarize_counters`); ``{}`` only for
+        runs that executed zero rounds.  The first read waits for the
+        run."""
+        if self._counters is None:
+            self._counters = summarize_counters(self.device_counters)
+            self.device_counters = None
+        return self._counters
 
     @classmethod
     def build(cls, spec: TelemetrySpec, executor: str, rounds: int,
               device_counters: Any = None, recorder: Any = None,
-              ssp: Any = None) -> "RunReport":
+              ssp: Any = None, events: Optional[List[dict]] = None
+              ) -> "RunReport":
         """Assemble from the run's raw pieces: the device counter pytree
-        off the final carry, the live Recorder (or None), and the SSP
-        summary (or None)."""
+        off the final carry (kept, not read), the run's events (or the
+        whole log of ``recorder``), and the SSP summary (or None)."""
+        if events is None:
+            events = (recorder.to_json_events()
+                      if recorder is not None else [])
         return cls(spec=spec, executor=executor, rounds=rounds,
-                   counters=summarize_counters(device_counters),
-                   events=(recorder.to_json_events()
-                           if recorder is not None else []),
-                   ssp=ssp)
+                   events=events, ssp=ssp, device_counters=device_counters)
 
     # -- export --------------------------------------------------------------
 
@@ -78,9 +97,9 @@ class RunReport:
         if c:
             lines.append(
                 f"  counters: rounds/phase {c['rounds_per_phase']}  "
-                f"sched_size {c['sched_size']}  rho-filter "
-                f"{c['accepted']}/{c['proposed']} kept "
-                f"({c['killed']} killed)")
+                f"rho-filter {c['accepted']}/{c['proposed']} kept "
+                f"({c['killed']} killed)  updated/visited "
+                f"{c.get('updated', 0)}/{c.get('visited', 0)}")
         if self.events:
             spans = [e for e in self.events if e.get("ph") == "X"]
             inst = len(self.events) - len(spans)
@@ -118,10 +137,10 @@ def report_from_json(obj: dict) -> RunReport:
     spec = TelemetrySpec.from_json(obj["spec"])
     rep = RunReport(spec=spec, executor=obj["executor"],
                     rounds=int(obj["rounds"]),
-                    counters=dict(obj.get("counters") or {}),
                     events=list(obj.get("events") or []),
                     ssp=_DictSection(obj["ssp"]) if obj.get("ssp")
-                    else None)
+                    else None,
+                    _counters=dict(obj.get("counters") or {}))
     return rep
 
 

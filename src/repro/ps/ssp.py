@@ -75,7 +75,6 @@ from jax.sharding import PartitionSpec as P
 from ..core.compat import shard_map
 from ..core.engine import DATA_AXIS
 from ..core.kvstore import VarTable
-from ..obs import counters as obs_counters
 from . import telemetry as T
 from .cache import StaleCache
 from .server import ParameterServer, init_clocks, tick
@@ -228,7 +227,8 @@ def _window_schedules(eng, hooks, view, sc, data, subs, ts, phases):
     marked = view
     marked_sc = sc
     for i, ((r1, _), t, ph) in enumerate(zip(keys, ts, phases)):
-        c = app.propose(marked, marked_sc, r1, t, ph)
+        with jax.named_scope("propose"):
+            c = app.propose(marked, marked_sc, r1, t, ph)
         cands.append(c)
         if i + 1 < len(subs):        # only later proposals see the mark
             marked = hooks.mark_scheduled(marked, c, ph)
@@ -238,15 +238,18 @@ def _window_schedules(eng, hooks, view, sc, data, subs, ts, phases):
             stats = [app.schedule_stats(data, st, c, ph)
                      for c, ph in zip(cands, phases)]
             return tuple(_batched_psum(stats, DATA_AXIS))
-        stats = shard_map(
-            stats_fn, mesh=eng.mesh,
-            in_specs=(eng.data_specs, eng._sspec(view), P()),
-            out_specs=P(),
-        )(data, view, tuple(cands))
+        with jax.named_scope("schedule_stats"):
+            stats = shard_map(
+                stats_fn, mesh=eng.mesh,
+                in_specs=(eng.data_specs, eng._sspec(view), P()),
+                out_specs=P(),
+            )(data, view, tuple(cands))
     else:
         stats = [None] * len(subs)
-    return [app.schedule(view, sc, c, s, r2, t, ph)
-            for c, s, (_, r2), t, ph in zip(cands, stats, keys, ts, phases)]
+    with jax.named_scope("schedule"):
+        return [app.schedule(view, sc, c, s, r2, t, ph)
+                for c, s, (_, r2), t, ph in zip(cands, stats, keys, ts,
+                                                phases)]
 
 
 def _fused_round(eng, hooks, view, data, sched, phase, nbytes_out: list):
@@ -259,12 +262,15 @@ def _fused_round(eng, hooks, view, data, sched, phase, nbytes_out: list):
     num_workers = eng.mesh.shape[DATA_AXIS]
 
     def f(data, st, sched):
-        z, local = app.push(data, st, sched, phase)
-        st = hooks.commit_local(st, sched, local, data, phase)
-        keep = hooks.defer_local(local, phase)
+        with jax.named_scope("push"):
+            z, local = app.push(data, st, sched, phase)
+            st = hooks.commit_local(st, sched, local, data, phase)
+            keep = hooks.defer_local(local, phase)
         nbytes_out.append(_tree_nbytes(z) * num_workers)
-        Z = jax.tree.map(lambda a: jax.lax.psum(a, DATA_AXIS), z)
-        return hooks.commit_shared(st, sched, Z, keep, data, phase)
+        with jax.named_scope("aggregate"):
+            Z = jax.tree.map(lambda a: jax.lax.psum(a, DATA_AXIS), z)
+        with jax.named_scope("pull"):
+            return hooks.commit_shared(st, sched, Z, keep, data, phase)
 
     return shard_map(f, mesh=eng.mesh,
                      in_specs=(eng.data_specs, sspec, P()),
@@ -281,9 +287,10 @@ def _push_round(eng, hooks, view, data, sched, phase):
     sspec = eng._sspec(view)
 
     def f(data, st, sched):
-        z, local = app.push(data, st, sched, phase)
-        st = hooks.commit_local(st, sched, local, data, phase)
-        keep = hooks.defer_local(local, phase)
+        with jax.named_scope("push"):
+            z, local = app.push(data, st, sched, phase)
+            st = hooks.commit_local(st, sched, local, data, phase)
+            keep = hooks.defer_local(local, phase)
         pend = jax.tree.map(lambda a: jnp.asarray(a)[None], (z, keep))
         return pend, st
 
@@ -299,7 +306,8 @@ def _flush_aggregate(eng, z_pends):
     """The lazy push: one batched psum over every deferred partial."""
     def f(zs):
         own = [jax.tree.map(lambda a: a[0], z) for z in zs]
-        return tuple(_batched_psum(own, DATA_AXIS))
+        with jax.named_scope("aggregate"):
+            return tuple(_batched_psum(own, DATA_AXIS))
 
     return shard_map(f, mesh=eng.mesh, in_specs=(P(DATA_AXIS),),
                      out_specs=P())(tuple(z_pends))
@@ -313,7 +321,8 @@ def _commit_round(eng, hooks, state, data, sched, z, keep_pend, phase):
 
     def f(data, st, sched, z, keep):
         local = jax.tree.map(lambda a: a[0], keep)
-        return hooks.commit_shared(st, sched, z, local, data, phase)
+        with jax.named_scope("pull"):
+            return hooks.commit_shared(st, sched, z, local, data, phase)
 
     return shard_map(
         f, mesh=eng.mesh,
@@ -387,8 +396,8 @@ def _build_ssp(eng, num_steps: int, staleness: int,
                     state = new_state
                     telem = T.observe_read(telem, ts[0], cache.clock)
                     if obs is not None:
-                        obs = obs_counters.observe_round(
-                            obs, scheds[0], phases[0], num_cand)
+                        obs = eng._observe(obs, data, scheds[0],
+                                           phases[0], num_cand)
                     clocks = tick(clocks)
                     if not info.get("traced"):
                         info["deferred_bytes_peak"] = max(
@@ -410,8 +419,8 @@ def _build_ssp(eng, num_steps: int, staleness: int,
                     keep_pends.append(kp)
                     telem = T.observe_read(telem, ts[k], cache.clock)
                     if obs is not None:
-                        obs = obs_counters.observe_round(
-                            obs, scheds[k], phases[k], num_cand)
+                        obs = eng._observe(obs, data, scheds[k],
+                                           phases[k], num_cand)
                     clocks = tick(clocks)
 
                 # The staleness bound now forces a sync: flush the pending

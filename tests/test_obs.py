@@ -139,7 +139,7 @@ def test_every_executor_returns_runreport(executor, mesh, rng):
     assert c["rounds"] == 8
     assert sum(c["rounds_per_phase"]) == 8
     assert c["accepted"] + c["killed"] == c["proposed"]
-    assert c["sched_size"] > 0
+    assert c["accepted"] > 0
     # every trace run records at least the execute > executor span pair
     names = [e["name"] for e in report.events]
     assert "execute" in names
@@ -197,7 +197,9 @@ def test_counter_identities_hold(steps, executor, scheduler):
     assert all(v >= 0 for v in c["rounds_per_phase"])
     assert c["accepted"] + c["killed"] == c["proposed"]
     assert 0 <= c["accepted"] <= c["proposed"]
-    assert c["sched_size"] == c["accepted"]
+    # accepted counts the admitted schedule entries: at most the
+    # schedule's width a round
+    assert c["accepted"] <= R * cfg.block_size
     if scheduler == "strads":
         # the dynamic-priority policy ρ-filters num_candidates per round
         assert c["proposed"] == R * cfg.num_candidates
@@ -405,7 +407,7 @@ def _valid_report_dict():
     return {"spec": {"kind": "counters", "profiler": False},
             "executor": "scan", "rounds": 4,
             "counters": {"rounds": 4, "rounds_per_phase": [4],
-                         "sched_size": 12, "proposed": 24,
+                         "proposed": 24,
                          "accepted": 12, "killed": 12},
             "events": [], "ssp": None}
 
@@ -422,7 +424,8 @@ def test_check_report_catches_broken_identities():
     assert "phase" in check_report(report_from_json(phases))
 
     negative = _valid_report_dict()
-    negative["counters"]["sched_size"] = -1
+    negative["counters"]["accepted"] = -1
+    negative["counters"]["killed"] = 25
     assert "negative" in check_report(report_from_json(negative))
 
     crossing = _valid_report_dict()
@@ -443,3 +446,282 @@ def test_extract_report_dicts_walks_nested_artifacts():
     assert extract_report_dicts({"no": "reports"}) == []
     # a bare to_json() dump is itself the report
     assert extract_report_dicts(rep) == [rep]
+
+
+# ---------------------------------------------------------------------------
+# span ids, the active Recorder, and what it records besides the engine
+# ---------------------------------------------------------------------------
+
+def test_spans_carry_ids_and_parents(tmp_path):
+    rec = Recorder()
+    with rec.span("outer"):
+        rec.instant("tick")
+        with rec.span("inner"):
+            pass
+        with rec.span("second"):
+            pass
+    ev = {e["name"]: e for e in rec.to_json_events()}
+    outer, inner, second = ev["outer"], ev["inner"], ev["second"]
+    assert len({outer["id"], inner["id"], second["id"]}) == 3
+    assert outer["parent"] is None
+    assert inner["parent"] == second["parent"] == outer["id"]
+    assert ev["tick"]["parent"] == outer["id"]
+    assert validate_spans(rec.to_json_events()) is None
+    # both exporters carry the new fields
+    doc = chrome_trace(rec.to_json_events())
+    args = {e["name"]: e["args"] for e in doc["traceEvents"]}
+    assert args["inner"]["parent"] == outer["id"]
+    assert args["outer"]["id"] == outer["id"]
+    lines = open(rec.write_jsonl(str(tmp_path / "e.jsonl"))).readlines()
+    assert {json.loads(x)["name"]: json.loads(x).get("parent")
+            for x in lines}["inner"] == outer["id"]
+
+
+def test_validate_spans_checks_parents():
+    outer = {"name": "a", "ph": "X", "ts": 0.0, "dur": 10.0, "id": 0,
+             "parent": None, "args": {}}
+    inside = {"name": "b", "ph": "X", "ts": 2.0, "dur": 3.0, "id": 1,
+              "parent": 0, "args": {}}
+    assert validate_spans([outer, inside]) is None
+    # a parent outside the log (an excerpt of a longer recording) is fine
+    assert validate_spans([dict(inside, parent=7)]) is None
+    # a span outside its parent, and an id used twice, are not
+    later = {"name": "c", "ph": "X", "ts": 12.0, "dur": 1.0, "id": 2,
+             "parent": 0, "args": {}}
+    assert "outside its parent" in validate_spans([outer, inside, later])
+    assert "twice" in validate_spans([outer, dict(inside, id=0)])
+
+
+def test_engine_spans_fall_back_to_the_active_recorder(mesh, rng):
+    from repro.obs import active, recording
+    X, y, _ = lasso.synthetic_correlated(rng, n=40, J=20, k_true=3)
+    cfg = lasso.LassoConfig(num_features=20, lam=0.02, block_size=4,
+                            num_candidates=8, rho=0.3)
+    eng = lasso.make_engine(cfg, mesh)
+    assert active() is None
+    rec = Recorder()
+    with recording(rec):
+        assert active() is rec
+        data = eng.shard_data({"X": jnp.asarray(X), "y": jnp.asarray(y)})
+        state = eng.init_state(jax.random.key(0), y=y)
+        rep = eng.execute(state, data, jax.random.key(1),
+                          _plan("scan", 4, False))
+    assert active() is None
+    assert rep.telemetry is None             # no spec: no report
+    ev = rec.to_json_events()
+    assert validate_spans(ev) is None
+    spans = {e["name"]: e for e in ev if e["ph"] == "X"}
+    for child, parent in [("app.init_state", "init_state"),
+                          ("place_state", "init_state"),
+                          ("execute.resolve", "execute"),
+                          ("scan", "execute"),
+                          ("scan.program", "scan"),
+                          ("scan.replicate", "scan"),
+                          ("scan.call", "scan")]:
+        assert spans[child]["parent"] == spans[parent]["id"], child
+    assert spans["shard_data"]["parent"] is None
+    assert any(e["name"] == "cache_miss" for e in ev)
+    # the compile of the round program ran inside the jitted call
+    calls = [e for e in ev if e["name"].startswith("jit.")]
+    assert {"jit.trace", "jit.lower", "jit.compile"} <= \
+        {e["name"] for e in calls}
+    assert any(e["parent"] == spans["scan.call"]["id"] for e in calls)
+    # nothing records once no Recorder is active
+    n = len(rec.to_json_events())
+    eng.execute(rep.state, data, jax.random.key(1), _plan("scan", 8, False),
+                carry=rep.carry)
+    assert len(rec.to_json_events()) == n
+
+
+def test_trace_execute_reports_its_own_events(mesh, rng):
+    from repro.obs import recording
+    eng, data, y = _lasso_engine(rng, mesh)
+    state = eng.init_state(jax.random.key(0), y=y)
+    rec = Recorder()
+    with recording(rec):
+        with rec.span("outer"):
+            rep = eng.execute(state, data, jax.random.key(1),
+                              _plan("scan", 4, TelemetrySpec(kind="trace")))
+    mine = rep.telemetry.events
+    names = [e["name"] for e in mine]
+    assert "execute" in names and "outer" not in names
+    # the call's events are in the active Recorder's log, inside "outer"
+    log = rec.to_json_events()
+    ids = {e["id"] for e in log if e["ph"] == "X"}
+    assert {e["id"] for e in mine if e["ph"] == "X"} <= ids
+    outer = next(e for e in log if e["name"] == "outer")
+    execute = next(e for e in mine if e["name"] == "execute")
+    assert execute["parent"] == outer["id"]
+    assert validate_spans(mine) is None and validate_spans(log) is None
+
+
+def test_jit_and_gc_spans_while_active():
+    import gc
+    from repro.obs import recording
+    rec = Recorder()
+    scale = np.float32(np.random.default_rng().random())
+
+    @jax.jit
+    def fresh(x):                       # a program no test compiled yet
+        return jnp.sin(x) * scale + x.size
+
+    with recording(rec):
+        fresh(jnp.arange(7.0)).block_until_ready()
+        gc.collect()
+    after = len(rec.to_json_events())
+    gc.collect()                        # not active any more: not recorded
+    ev = rec.to_json_events()
+    assert len(ev) == after
+    jit = [e for e in ev if e["name"].startswith("jit.")]
+    assert [e["name"] for e in sorted(jit, key=lambda e: e["ts"])][:1] \
+        == ["jit.trace"]
+    assert {"jit.trace", "jit.lower", "jit.compile"} <= \
+        {e["name"] for e in jit}
+    assert all(e["dur"] >= 0 for e in jit)
+    assert any("fresh" in e["args"]["fun"] for e in jit)
+    full = [e for e in ev if e["name"] == "gc"
+            and e["args"]["generation"] == 2]
+    assert full and "collected" in full[-1]["args"]
+    assert validate_spans(ev) is None
+
+
+def test_span_ended_nests_what_it_covers():
+    rec = Recorder()
+    with rec.span("call"):
+        with rec.span("gc"):
+            pass
+        ev = rec.span_ended("jit.trace", 10.0)     # longer than "call"
+    log = rec.to_json_events()
+    call = next(e for e in log if e["name"] == "call")
+    assert ev["ts"] == call["ts"]              # clamped into its parent
+    assert ev["parent"] == call["id"]
+    assert validate_spans(log) is None
+
+
+# ---------------------------------------------------------------------------
+# the RunReport reads the device only when asked
+# ---------------------------------------------------------------------------
+
+def test_runreport_keeps_counters_on_the_device(mesh, rng):
+    eng, data, y = _lasso_engine(rng, mesh)
+    rep = eng.execute(eng.init_state(jax.random.key(0), y=y), data,
+                      jax.random.key(1),
+                      _plan("scan", 4, TelemetrySpec(kind="counters")))
+    report = rep.telemetry
+    assert report.rounds == 4                      # the host's index
+    leaves = jax.tree_util.tree_leaves(report.device_counters)
+    assert leaves and all(isinstance(x, jax.Array) for x in leaves)
+    c = report.counters                            # the first read
+    assert report.device_counters is None
+    assert c["rounds"] == 4 and report.counters is c
+    assert report.to_json()["counters"] == c
+
+
+def test_wide_counts_do_not_wrap():
+    from repro.obs.counters import _add_wide, _wide
+    c = jnp.zeros((2,), jnp.int32)
+    step = (1 << 30) - 1
+    for _ in range(5):
+        c = _add_wide(c, step)
+    assert _wide(c) == 5 * step > 2 ** 31
+
+
+# ---------------------------------------------------------------------------
+# visited / updated: what LDA's Gibbs scans step over and sample
+# ---------------------------------------------------------------------------
+
+def test_lda_one_worker_samples_every_token_it_visits(mesh, rng):
+    cfg = lda.LDAConfig(vocab=30, num_topics=4, num_workers=1,
+                        tokens_per_worker=200, docs_per_worker=5)
+    words, docs, z0 = lda.synthetic_corpus(rng, cfg, true_topics=4)
+    words[::7] = -1                                 # padding slots
+    valid = int((words >= 0).sum())
+    eng = lda.make_engine(cfg, mesh)
+    data = eng.shard_data({"words": jnp.asarray(words),
+                           "docs": jnp.asarray(docs)})
+    for executor in EXECUTORS:
+        state = eng.init_state(jax.random.key(0), words=words, docs=docs,
+                               z0=z0)
+        c = eng.execute(state, data, jax.random.key(1),
+                        _plan(executor, 6, TelemetrySpec(kind="counters"))
+                        ).telemetry.counters
+        assert c["visited"] == c["updated"] == 6 * valid, executor
+
+
+def test_lda_four_workers_sample_a_quarter_of_what_they_visit():
+    """Four forced host devices, one rotation (4 rounds): every worker
+    scans all its tokens each round and samples those of its current
+    block, so over a rotation ``updated`` is a quarter of ``visited`` —
+    and the counters leave the state bit-identical."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = textwrap.dedent("""
+        import numpy as np, jax, jax.numpy as jnp
+        from repro.apps import lda
+        from repro.core import ExecutionPlan, worker_mesh
+        from repro.obs import TelemetrySpec
+        cfg = lda.LDAConfig(vocab=40, num_topics=4, num_workers=4,
+                            tokens_per_worker=64, docs_per_worker=4)
+        words, docs, z0 = lda.synthetic_corpus(np.random.default_rng(0),
+                                               cfg, true_topics=4)
+        words[::5] = -1
+        eng = lda.make_engine(cfg, worker_mesh(4))
+        data = eng.shard_data({"words": jnp.asarray(words),
+                               "docs": jnp.asarray(docs)})
+        out = {}
+        for tel in (False, TelemetrySpec(kind="counters")):
+            st = eng.init_state(jax.random.key(0), words=words, docs=docs,
+                                z0=z0)
+            rep = eng.execute(st, data, jax.random.key(1),
+                              ExecutionPlan(executor="scan", rounds=8,
+                                            telemetry=tel))
+            out[bool(tel)] = rep
+        c = out[True].telemetry.counters
+        valid = int((words >= 0).sum())
+        assert c["visited"] == 8 * valid, c
+        assert 4 * c["updated"] == c["visited"], c
+        for k in out[False].state:
+            a = np.asarray(out[False].state[k])
+            b = np.asarray(out[True].state[k])
+            assert (a == b).all(), k
+        print("OK", c["visited"], c["updated"])
+    """)
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=540)
+    assert p.returncode == 0, p.stderr[-4000:]
+    assert p.stdout.startswith("OK")
+
+
+def test_telemetry_cost_script_runs_tiny():
+    """``benchmarks/telemetry_cost.py`` on the CPU at the cell's tiny
+    size: its set-up spans cover the set-up, and a traced window's device
+    counter ``updated`` equals the tokens the host counts as sampled.
+    (CPU timings: no cost is read here.)"""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmarks",
+                                      "telemetry_cost.py"),
+         "--tiny", "--windows", "1", "--seconds", "0.2"],
+        capture_output=True, text=True, env=env, timeout=540)
+    assert p.returncode == 0, p.stderr[-4000:]
+    lines = [json.loads(x) for x in p.stdout.strip().splitlines()]
+    setup, windows, summary = lines[0], lines[1:-1], lines[-1]
+    assert setup["platform"] == "cpu"
+    assert set(setup["spans_s"]) == {"import", "backend_start", "setup",
+                                     "warm"}
+    assert setup["covered_share"] >= 0.95
+    assert [w["mode"] for w in windows] == ["off", "trace"]
+    traced = windows[1]
+    assert traced["updated"] == traced["visited"] == traced["updates"] > 0
+    assert traced["events"] > 0
+    assert set(summary) >= {"updates_per_s_off", "updates_per_s_trace",
+                            "trace_cost"}
