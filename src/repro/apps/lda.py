@@ -45,6 +45,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import StradsAppBase, StradsEngine
 from repro.core.compat import shard_map
+from repro.kernels import split_chain
 from repro.part import PartitionerSpec
 from repro.sched import SchedulerSpec
 
@@ -75,34 +76,58 @@ def _gibbs_scan(cfg: LDAConfig, B, D, s, words, docs, z, active_mask,
     """Sequential collapsed Gibbs over one worker's scheduled tokens.
 
     Exact within the worker (counts updated after every sample); the only
-    stale quantity is s̃, which starts at the synced s."""
+    stale quantity is s̃, which starts at the synced s.  ``B`` holds the
+    word rows ``[block_start, block_start + B.shape[0])``.
+
+    A token step reads its word's row of B and its document's row of D
+    once, stacks them with s̃, takes the token's topic out of all three,
+    draws, adds the new topic back and writes each row once.  What does
+    not depend on the counts is computed for every token before the loop
+    and packed so that one slice fetches a token: its local word row, its
+    document, its topic, an offset that turns off an unscheduled token
+    and its subkey of the sampler's split chain.  The draw is written
+    back into the token's topic field."""
     K = cfg.num_topics
+    # an unscheduled token takes topic K out and adds K back: no topic
+    off = jnp.where(active_mask, 0, K)
+    vloc = jnp.clip(words - block_start, 0, B.shape[0] - 1)
+    toks = jnp.concatenate(
+        [jnp.stack([vloc, docs, z + off, off], axis=1).astype(jnp.uint32),
+         split_chain.subkeys(rng, words.shape[0])], axis=1)
+    impl = jax.random.key_impl(rng)
+    topics = jax.lax.iota(jnp.uint32, K)
+    zero = jnp.uint32(0)
 
-    def body(carry, tok):
-        B, D, st, key = carry
-        v, d, zi, active = tok
-        a = active.astype(B.dtype)
-        vloc = jnp.clip(v - block_start, 0, cfg.block_vocab - 1)
-        # remove current assignment
-        B = B.at[vloc, zi].add(-a)
-        D = D.at[d, zi].add(-a)
-        st = st.at[zi].add(-a)
+    def step(i, carry):
+        B, D, st, toks = carry
+        tok = toks[i]
+        v, d, zi, o = tok[0], tok[1], tok[2], tok[3]
+        rows = jnp.stack([jax.lax.dynamic_slice(B, (v, zero), (1, K))[0],
+                          jax.lax.dynamic_slice(D, (d, zero), (1, K))[0],
+                          st])
+        # remove the current assignment (x - 1 is the scatter's x + (-1))
+        rows = jnp.where(topics == zi, rows - 1, rows)
         # conditional:  (γ+B[v,k]) / (Vγ+s̃[k]) · (α+D[d,k])
-        logits = (jnp.log(cfg.gamma + B[vloc]) -
-                  jnp.log(cfg.padded_vocab * cfg.gamma + st) +
-                  jnp.log(cfg.alpha + D[d]))
-        key, sub = jax.random.split(key)
-        znew = jax.random.categorical(sub, logits)
-        znew = jnp.where(active, znew, zi).astype(zi.dtype)
-        # add back
-        B = B.at[vloc, znew].add(a)
-        D = D.at[d, znew].add(a)
-        st = st.at[znew].add(a)
-        return (B, D, st, key), znew
+        logits = (jnp.log(cfg.gamma + rows[0]) -
+                  jnp.log(cfg.padded_vocab * cfg.gamma + rows[2]) +
+                  jnp.log(cfg.alpha + rows[1]))
+        sub = jax.random.wrap_key_data(tok[4:], impl=impl)
+        znew = jax.random.categorical(sub, logits).astype(jnp.uint32)
+        # add back (nowhere for an unscheduled token)
+        rows = jnp.where(topics - o == znew, rows + 1, rows)
+        B = jax.lax.dynamic_update_slice(B, rows[0][None], (v, zero))
+        D = jax.lax.dynamic_update_slice(D, rows[1][None], (d, zero))
+        toks = jax.lax.dynamic_update_slice(toks, znew[None, None], (i, 2))
+        return B, D, rows[2], toks
 
-    (B, D, st, _), z_new = jax.lax.scan(
-        body, (B, D, s, rng), (words, docs, z, active_mask))
-    return B, D, st, z_new
+    # D goes through the loop as a copy one row longer: a buffer of its
+    # own, which the compiler can keep on chip, where the state's
+    # (donated) buffer stays in HBM
+    B, D, st, toks = jax.lax.fori_loop(
+        0, toks.shape[0], step,
+        (B, jnp.pad(D, ((0, 1), (0, 0))), s, toks))
+    z_new = jnp.where(active_mask, toks[:, 2].astype(z.dtype), z)
+    return B, D[:-1], st, z_new
 
 
 class StradsLDA(StradsAppBase):
@@ -110,7 +135,8 @@ class StradsLDA(StradsAppBase):
 
     supported_scheduler_kinds = ("rotation",)
     # Gibbs sampling is gather/scan-bound, not matmul-bound: no Pallas
-    # hot-spot exists, so a plan asking for one is rejected at injection.
+    # hot-spot a plan could swap exists (the split chain's kernel goes by
+    # platform), so a plan asking for one is rejected at injection.
     supported_kernel_kinds = ("reference",)
 
     def __init__(self, cfg: LDAConfig):
@@ -336,33 +362,6 @@ class StradsLDA(StradsAppBase):
 # Data-parallel baseline (YahooLDA-style)
 # ---------------------------------------------------------------------------
 
-def _full_gibbs_scan(cfg: LDAConfig, B, D, s, words, docs, z, active_mask,
-                     rng):
-    """Gibbs over the full vocab table (data-parallel baseline)."""
-    def body(carry, tok):
-        B, D, st, key = carry
-        v, d, zi, active = tok
-        a = active.astype(B.dtype)
-        vc = jnp.clip(v, 0, cfg.padded_vocab - 1)
-        B = B.at[vc, zi].add(-a)
-        D = D.at[d, zi].add(-a)
-        st = st.at[zi].add(-a)
-        logits = (jnp.log(cfg.gamma + B[vc]) -
-                  jnp.log(cfg.padded_vocab * cfg.gamma + st) +
-                  jnp.log(cfg.alpha + D[d]))
-        key, sub = jax.random.split(key)
-        znew = jax.random.categorical(sub, logits)
-        znew = jnp.where(active, znew, zi).astype(zi.dtype)
-        B = B.at[vc, znew].add(a)
-        D = D.at[d, znew].add(a)
-        st = st.at[znew].add(a)
-        return (B, D, st, key), znew
-
-    (B, D, st, _), z_new = jax.lax.scan(
-        body, (B, D, s, rng), (words, docs, z, active_mask))
-    return B, D, st, z_new
-
-
 class DataParallelLDAApp(StradsAppBase):
     """Working data-parallel baseline app."""
 
@@ -388,9 +387,10 @@ class DataParallelLDAApp(StradsAppBase):
         active = words >= 0
         p = jax.lax.axis_index("data")
         rng = jax.random.fold_in(jax.random.key(23), p)
-        B, D, s_tilde, z_new = _full_gibbs_scan(
+        # the full table is one block starting at word 0
+        B, D, s_tilde, z_new = _gibbs_scan(
             cfg, state["B"], state["D"], state["s"], words, docs, z,
-            active, rng)
+            active, 0, rng)
         partial = {"dB": B - state["B"]}
         local = {"z": z_new, "D": D}
         return partial, local

@@ -9,6 +9,8 @@ spec.py    — KernelSpec: the declarative backend choice carried as
              scheduler/partitioner spec pattern)
 backend.py — build_kernels registry resolving a spec into an executable
              backend (Pallas on TPU, interpret-mode fallback elsewhere)
+split_chain.py — a key's split chain drawn on the TPU's scalar core (no
+             spec: chosen by platform, the same bits as ``lax.scan``)
 """
 from . import ops, ref  # noqa: F401
 from .backend import (KERNEL_BACKENDS, PallasKernels,  # noqa: F401

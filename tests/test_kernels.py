@@ -19,6 +19,7 @@ from repro.kernels import (KERNEL_KINDS, KernelSpec, PallasKernels,
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.lasso_cd import DEFAULT_BLOCK_N, gram_block, lasso_partial
 from repro.kernels.moe_gating import topk_gating
+from repro.kernels import split_chain
 from repro.kernels.ssm_scan import ssm_scan
 
 R = np.random.default_rng(42)
@@ -222,6 +223,40 @@ def test_lasso_partial_property(n, u, bn):
     got = lasso_partial(X, r, block_n=bn, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# split chain
+# ---------------------------------------------------------------------------
+
+def _split_loop(key, n):
+    subs = []
+    for _ in range(n):
+        key, sub = jax.random.split(key)
+        subs.append(np.asarray(jax.random.key_data(sub)))
+    return np.stack(subs)
+
+
+# within one block, exactly one, and over a block boundary
+@pytest.mark.parametrize("seed,n", [(0, 5), (1, split_chain.BLOCK),
+                                    (2**31 + 7, split_chain.BLOCK + 3)])
+def test_split_chain_kernel_gives_the_split_bits(seed, n):
+    """The scalar-core kernel (interpret mode) and the scan both give the
+    subkeys a loop of ``jax.random.split`` gives, bit for bit."""
+    key = jax.random.fold_in(jax.random.key(17), seed % 2**32)
+    want = np.asarray(split_chain._scan_chain(key, n))
+    np.testing.assert_array_equal(want[:5], _split_loop(key, 5))
+    got = split_chain._pallas_chain(key, n, interpret=True)
+    assert got.dtype == jnp.uint32 and got.shape == (n, 2)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(
+        np.asarray(split_chain.subkeys(key, n)), want)
+
+
+def test_split_chain_other_key_impl_scans_splits():
+    key = jax.random.key(3, impl="rbg")
+    np.testing.assert_array_equal(np.asarray(split_chain.subkeys(key, 4)),
+                                  _split_loop(key, 4))
 
 
 # ---------------------------------------------------------------------------

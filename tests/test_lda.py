@@ -1,11 +1,22 @@
 """STRADS LDA: count conservation, likelihood ascent, s-error bounds,
-single-worker exactness."""
+single-worker exactness, and the Gibbs scan against the plain reference."""
+import functools
+import os
+import sys
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.apps import lda
 from repro.core import single_device_mesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from bench.reference import lda as ref  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -94,3 +105,50 @@ def test_block_partition_covers_vocab():
     assert cfg.padded_vocab == cfg.block_vocab * cfg.num_workers
     blocks = np.arange(cfg.vocab) // cfg.block_vocab
     assert blocks.max() < cfg.num_workers
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_gibbs_scan_bit_identical_to_reference(seed):
+    """Each worker's Gibbs scan draws the plain reference's topics and
+    leaves its counts, bit for bit, at the benchmark's tiny sizes: two
+    vocab blocks (so a worker skips the other block's words), -1
+    padding, the same word on runs of consecutive tokens (a row read
+    right after it was written) and draws that keep the old topic."""
+    V, K, T, dpw = 600, 64, 4096, 16
+    cfg = lda.LDAConfig(vocab=V, num_topics=K, num_workers=2,
+                        tokens_per_worker=T, docs_per_worker=dpw)
+    Vb, Vp = cfg.block_vocab, cfg.padded_vocab
+    r = np.random.default_rng(seed)
+    # Zipf-like words, so a few rows carry most of the counts
+    words = np.minimum(r.zipf(1.3, T) - 1, V - 1).astype(np.int32)
+    runs = np.flatnonzero(r.random(T - 1) < 0.1)
+    words[runs + 1] = words[runs]
+    words[r.random(T) < 0.05] = -1
+    docs = r.integers(0, dpw, T).astype(np.int32)
+    # concentrated start, so many draws keep their topic
+    z = r.integers(0, 4, T).astype(np.int32)
+    B, D, s = ref.counts(words, docs, z, W=1, Vp=Vp, dpw=dpw, K=K)
+    scan = jax.jit(functools.partial(lda._gibbs_scan, cfg))
+    want_fn = jax.jit(functools.partial(ref._gibbs, Vb=Vb, Vp=Vp,
+                                        alpha=cfg.alpha, gamma=cfg.gamma))
+    for block in range(cfg.num_workers):
+        active = (words >= 0) & (words // Vb == block)
+        key = jax.random.fold_in(
+            jax.random.fold_in(jax.random.key(ref.SAMPLER_KEY), 0), block)
+        rows = slice(block * Vb, (block + 1) * Vb)
+        got = scan(B[rows], D, s, words, docs, z, active,
+                   jnp.int32(block * Vb), key)
+        want = want_fn(B[rows], D, s, words, docs, z, active,
+                       block * Vb, key)
+        B_got, D_got, s_got, z_got = (np.asarray(x) for x in got)
+        B_want, D_want, z_want = (np.asarray(x) for x in want)
+        s_want = s + (B_want.sum(axis=0) - B[rows].sum(axis=0))
+        assert np.array_equal(z_got, z_want)
+        assert np.array_equal(B_got, B_want)
+        assert np.array_equal(D_got, D_want)
+        assert np.array_equal(s_got, s_want)
+        # the cases the scan must get right were all exercised
+        assert np.array_equal(z_got[~active], z[~active])
+        assert np.any(active[runs] & active[runs + 1])
+        kept, moved = active & (z_got == z), active & (z_got != z)
+        assert kept.sum() > 10 and moved.sum() > 10
