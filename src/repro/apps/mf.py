@@ -24,9 +24,10 @@ inert.  W shards with the rows (model partitioning — Fig 3); H is the
 synced KV-store block (replicated; K×M is small beside W for N ≫ M).  The
 W-phase uses the same row shards: for fixed k, w_ik ← Σ_j … over the
 row's entries, which are whole on one worker, so it needs no cross-worker
-sum (the paper's submatrix A^{q_p} storage).  Every sweep is gathers,
-segment sums and an elementwise residual update: no matrix product, so
-float32 stays float32 on the TPU.
+sum (the paper's submatrix A^{q_p} storage).  Every sweep is indexed
+reads of a factor vector, segment sums and an elementwise residual
+update (``kernels/entry_sweep.py``: one-hot contractions on a TPU, exact
+in float32; XLA's gathers and scatters elsewhere).
 """
 from __future__ import annotations
 
@@ -42,6 +43,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import StradsAppBase, StradsEngine
 from repro.core.compat import shard_map
+from repro.kernels import entry_sweep
 from repro.obs import active
 from repro.part import PartitionerSpec
 from repro.sched import SchedulerSpec
@@ -87,8 +89,10 @@ def layout(rows, cols, vals, num_rows: int, *, num_workers: int = 1,
 
     ``row``/``col``/``val`` are ``(P·capacity,)``; ``valid`` ``(N,)``
     marks the rows that hold a user (default: those with a rating).  A
-    span ``mf.layout`` in the active Recorder."""
-    with _span("mf.layout", ratings=int(np.size(rows))):
+    span ``mf.layout`` in the active Recorder, whose args ``row_steps``
+    and ``col_steps`` are the contraction steps one sweep by row and by
+    column takes over the shards (:func:`entry_sweep.sweep_steps`)."""
+    with _span("mf.layout", ratings=int(np.size(rows))) as ev:
         rows = np.asarray(rows, np.int64)
         cols = np.asarray(cols, np.int32)
         vals = np.asarray(vals, np.float32)
@@ -123,6 +127,10 @@ def layout(rows, cols, vals, num_rows: int, *, num_workers: int = 1,
         if valid is None:
             valid = np.zeros(num_rows, bool)
             valid[rows] = True
+        if ev is not None:
+            ev["args"].update(
+                row_steps=sum(map(entry_sweep.sweep_steps, out_row)),
+                col_steps=sum(map(entry_sweep.sweep_steps, out_col)))
         return {"row": out_row.reshape(-1), "col": out_col.reshape(-1),
                 "val": out_val.reshape(-1), "valid": np.asarray(valid, bool)}
 
@@ -156,12 +164,6 @@ def _residual(W, H, row, col, val, *, workers: int):
     return out.reshape(-1)
 
 
-def _segment_sums(x, y, ids, n: int):
-    """Σ x and Σ y by segment, as two 1-D scatters (a stacked (C, 2) pair
-    would be tiled to 128 lanes on a TPU: 64 times its size)."""
-    return (jax.ops.segment_sum(x, ids, n), jax.ops.segment_sum(y, ids, n))
-
-
 class StradsMF(StradsAppBase):
     """Round-robin rank-wise CD on STRADS primitives, over sparse
     per-shard ratings."""
@@ -170,8 +172,8 @@ class StradsMF(StradsAppBase):
     # rank blocks are mutually independent given the other factor — no
     # dependency filter applies, so only the stateless dispatch kinds
     supported_scheduler_kinds = ("round_robin", "random")
-    # the sweeps are gathers and segment sums with no fused Pallas kernel
-    # — only the reference backend applies, enforced at injection time
+    # the sweeps' kernels (kernels/entry_sweep.py) go by platform, not by
+    # KernelSpec — only the reference kind applies, enforced at injection
     supported_kernel_kinds = ("reference",)
 
     def __init__(self, cfg: MFConfig, num_workers: int = 1):
@@ -315,9 +317,10 @@ class StradsMF(StradsAppBase):
             ab = []
             with jax.named_scope("sweep"):
                 for i in range(ks.shape[0]):
-                    w = self._ext(W[:, ks[i]])[row]
-                    h = H[ks[i]][col]
-                    ab.append(_segment_sums((R + w * h) * w, w * w, col, M))
+                    w = entry_sweep.take(self._ext(W[:, ks[i]]), row)
+                    h = entry_sweep.take(H[ks[i]], col)
+                    ab.append(entry_sweep.segment_sums((R + w * h) * w,
+                                                       w * w, col, M))
             return {"a": jnp.stack([x[0] for x in ab]),
                     "b": jnp.stack([x[1] for x in ab])}, None
         # W-phase: rows are whole on this worker — no cross-worker sum;
@@ -335,21 +338,22 @@ class StradsMF(StradsAppBase):
             H_new = z["a"] / (lam + z["b"])                        # g₃
             with jax.named_scope("sweep"):
                 for i in range(ks.shape[0]):                       # sync
-                    w = self._ext(W[:, ks[i]])[row]
-                    R = R - w * (H_new[i] - H[ks[i]])[col]
+                    w = entry_sweep.take(self._ext(W[:, ks[i]]), row)
+                    R = R - w * entry_sweep.take(H_new[i] - H[ks[i]], col)
             return {"W": W, "H": H.at[ks].set(H_new), "R": R}
         # W-phase (local closed-form CD for the rank block on local rows)
-        W_new = []
+        W_new, hs = [], []
         with jax.named_scope("sweep"):
             for i in range(ks.shape[0]):
-                w_old = self._ext(W[:, ks[i]])
-                w = w_old[row]
-                h = H[ks[i]][col]
-                num, den = _segment_sums((R + w * h) * h, h * h, row, Np + 1)
+                w = entry_sweep.take(self._ext(W[:, ks[i]]), row)
+                h = entry_sweep.take(H[ks[i]], col)
+                hs.append(h)
+                num, den = entry_sweep.segment_sums((R + w * h) * h, h * h,
+                                                    row, Np + 1)
                 W_new.append((num / (lam + den))[:Np])
             for i in range(ks.shape[0]):
                 dw = self._ext(W_new[i]) - self._ext(W[:, ks[i]])
-                R = R - dw[row] * H[ks[i]][col]
+                R = R - entry_sweep.take(dw, row) * hs[i]
         W = W.at[:, ks].set(jnp.stack(W_new, axis=1))
         return {"W": W, "H": H, "R": R}
 

@@ -9,6 +9,9 @@ spec.py    — KernelSpec: the declarative backend choice carried as
              scheduler/partitioner spec pattern)
 backend.py — build_kernels registry resolving a spec into an executable
              backend (Pallas on TPU, interpret-mode fallback elsewhere)
+entry_sweep.py — MF's indexed reads and segment sums over sparse entries
+             as one-hot contractions on the MXU (no spec: chosen by
+             platform, exact in float32)
 split_chain.py — a key's split chain drawn on the TPU's scalar core (no
              spec: chosen by platform, the same bits as ``lax.scan``)
 """

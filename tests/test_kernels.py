@@ -19,7 +19,7 @@ from repro.kernels import (KERNEL_KINDS, KernelSpec, PallasKernels,
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.lasso_cd import DEFAULT_BLOCK_N, gram_block, lasso_partial
 from repro.kernels.moe_gating import topk_gating
-from repro.kernels import split_chain
+from repro.kernels import entry_sweep, split_chain
 from repro.kernels.ssm_scan import ssm_scan
 
 R = np.random.default_rng(42)
@@ -257,6 +257,103 @@ def test_split_chain_other_key_impl_scans_splits():
     key = jax.random.key(3, impl="rbg")
     np.testing.assert_array_equal(np.asarray(split_chain.subkeys(key, 4)),
                                   _split_loop(key, 4))
+
+
+# ---------------------------------------------------------------------------
+# entry sweeps (MF's indexed reads and segment sums)
+# ---------------------------------------------------------------------------
+
+def _gapped_rows(n, C):
+    """Sorted indices in three clusters steps apart: one block spans
+    every step of an n = 120,049 table."""
+    r = np.random.default_rng(5)
+    parts = [r.integers(0, 100, C // 3), r.integers(40000, 40100, C // 3),
+             r.integers(n - 60, n, C - 2 * (C // 3))]
+    return np.sort(np.concatenate(parts))
+
+
+SWEEP_CASES = {
+    # name: (n, C, indices from (rng, n, C))
+    "random_columns": (17770, 5000, lambda r, n, C: r.integers(0, n, C)),
+    "sorted_rows": (120049, 9000,
+                    lambda r, n, C: np.sort(r.integers(0, n, C))),
+    "gapped_rows": (120049, entry_sweep.BLOCK,
+                    lambda r, n, C: _gapped_rows(n, C)),
+    "all_on_the_sentinel": (120049, 2000,
+                            lambda r, n, C: np.full(C, n - 1)),
+    "ragged": (300, 1000, lambda r, n, C: r.integers(0, n, C)),
+}
+
+
+def _sweep_case(name):
+    n, C, draw = SWEEP_CASES[name]
+    r = np.random.default_rng(sorted(SWEEP_CASES).index(name))
+    idx = np.asarray(draw(r, n, C), np.int32)
+    v = jnp.asarray(r.normal(size=n).astype(np.float32) * 0.03)
+    x, y = (jnp.asarray(r.normal(size=C).astype(np.float32))
+            for _ in range(2))
+    return n, jnp.asarray(idx), v, x, y
+
+
+@pytest.mark.parametrize("name", sorted(SWEEP_CASES))
+def test_entry_take_is_bit_exact(name):
+    """The one-hot contraction (interpret mode) returns v[idx] bit for
+    bit: three bfloat16 parts of each float32, float32 accumulation."""
+    n, idx, v, _, _ = _sweep_case(name)
+    got = entry_sweep.take_kernel(v, idx, interpret=True)
+    assert got.shape == idx.shape and got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(v[idx]))
+
+
+@pytest.mark.parametrize("name", sorted(SWEEP_CASES))
+def test_entry_segment_sums_match_segment_sum(name):
+    """Both sums agree with ``jax.ops.segment_sum`` within 1e-6 of the
+    sum of the magnitudes in each segment (float32 added in another
+    order)."""
+    n, idx, _, x, y = _sweep_case(name)
+    got = entry_sweep.segment_sums_kernel(x, y, idx, n, interpret=True)
+    for a, val in zip(got, (x, y)):
+        assert a.shape == (n,)
+        want = jax.ops.segment_sum(val, idx, n)
+        scale = jax.ops.segment_sum(jnp.abs(val), idx, n)
+        assert np.all(np.abs(np.asarray(a - want))
+                      <= 1e-6 * np.asarray(scale))
+
+
+@pytest.mark.parametrize("name", sorted(SWEEP_CASES))
+def test_entry_sweep_steps_count_the_kernels_windows(name):
+    """The host count of contraction steps is the steps the kernels'
+    windows (their scalar prefetch) make them take."""
+    n, idx, _, _, _ = _sweep_case(name)
+    lo, hi = entry_sweep._windows(entry_sweep._rows(idx),
+                                  entry_sweep._steps(n))
+    assert entry_sweep.sweep_steps(np.asarray(idx)) == int(
+        jnp.sum(hi - lo + 1))
+
+
+def test_entry_sweep_steps_follow_the_indices_span():
+    """Sorted rows take about one step a block, random columns below
+    17,770 two, and a block of rows in clusters steps apart every step
+    between them."""
+    r = np.random.default_rng(0)
+    C, B = 40 * entry_sweep.BLOCK, entry_sweep.BLOCK
+    rows = np.sort(r.integers(0, 120049, C))
+    assert C // B <= entry_sweep.sweep_steps(rows) <= C // B + 8
+    assert entry_sweep.sweep_steps(r.integers(0, 17770, C)) == 2 * C // B
+    assert entry_sweep.sweep_steps(
+        _gapped_rows(120049, entry_sweep.BLOCK)) == 8
+
+
+def test_entry_sweep_off_the_tpu_is_xla():
+    """Off a TPU the sweeps are XLA's gather and scatter-adds."""
+    n, idx, v, x, y = _sweep_case("random_columns")
+    np.testing.assert_array_equal(np.asarray(entry_sweep.take(v, idx)),
+                                  np.asarray(v[idx]))
+    a, b = jax.jit(entry_sweep.segment_sums, static_argnums=3)(x, y, idx, n)
+    np.testing.assert_array_equal(np.asarray(a),
+                                  np.asarray(jax.ops.segment_sum(x, idx, n)))
+    np.testing.assert_array_equal(np.asarray(b),
+                                  np.asarray(jax.ops.segment_sum(y, idx, n)))
 
 
 # ---------------------------------------------------------------------------

@@ -140,6 +140,48 @@ def test_sparse_layout_matches_dense_reference(executor, mesh, problem,
     _check_against_reference(eng, data, st0, final, A, mask, rounds, 2)
 
 
+@pytest.fixture
+def kernel_sweeps(monkeypatch):
+    """The sweeps' Pallas kernels in interpret mode, in place of XLA's
+    gather and scatter that the CPU runs."""
+    from functools import partial
+    from repro.kernels import entry_sweep
+    monkeypatch.setattr(entry_sweep, "take",
+                        partial(entry_sweep.take_kernel, interpret=True))
+    monkeypatch.setattr(entry_sweep, "segment_sums", partial(
+        entry_sweep.segment_sums_kernel, interpret=True))
+
+
+@pytest.mark.parametrize("case", ["ranks_1", "ranks_2", "after_ingest"])
+def test_kernel_sweeps_agree_with_the_xla_path(case, mesh, problem,
+                                               request):
+    """Two H/W cycles through the sweeps' kernels (interpret mode) agree
+    with the same cycles through XLA's gathers and segment sums: the
+    reads are exact, the sums float32 in another order."""
+    A, mask = problem
+    cfg = mf.MFConfig(num_rows=60, num_cols=40, rank=6, lam=0.05,
+                      ranks_per_round=2 if case == "ranks_2" else 1)
+    eng, data, st, st0 = _setup(mesh, problem, cfg)
+    if case == "after_ingest":
+        delta = {"data": {"col": np.array([[0, 7, 39], [3, -1, -1]],
+                                          np.int32),
+                          "val": np.array([[4., 5., 2.], [1., 0., 0.]],
+                                          np.float32)}}
+        data, st = eng.app.ingest(data, st, np.array([11, 52]), delta)
+        data = eng.shard_data(data)
+        st0 = {k: np.array(v) for k, v in st.items()}
+    plan = ExecutionPlan(executor="scan", rounds=4)
+    want = eng.execute(st, data, jax.random.key(3), plan).state
+    request.getfixturevalue("kernel_sweeps")
+    eng = mf.make_engine(cfg, mesh)
+    got = eng.execute(eng.place_state({k: jnp.asarray(v)
+                                       for k, v in st0.items()}),
+                      data, jax.random.key(3), plan).state
+    for k in ("W", "H", "R"):
+        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
+                                   rtol=1e-5, atol=1e-6)
+
+
 def test_four_devices_through_the_h_phase_psum():
     """Four row shards on four forced host devices: the H-phase partial
     sums meet in the psum, and the result agrees with the dense

@@ -769,11 +769,16 @@ def test_mf_layout_is_a_span_of_the_active_recorder(rng):
     A, mask = mf.synthetic_ratings(rng, 8, 6, true_rank=2)
     rec = Recorder()
     with recording(rec):
-        mf.StradsMF(mf.MFConfig(num_rows=8, num_cols=6, rank=2),
-                    num_workers=2).layout(A, mask)
+        data = mf.StradsMF(mf.MFConfig(num_rows=8, num_cols=6, rank=2),
+                           num_workers=2).layout(A, mask)
     spans = [e for e in rec.to_json_events() if e["name"] == "mf.layout"]
     assert len(spans) == 1 and spans[0]["ph"] == "X"
     assert spans[0]["args"]["ratings"] == int(mask.sum())
+    # a sweep's contraction steps by row and by column, over both shards
+    from repro.kernels.entry_sweep import sweep_steps
+    for arg, key in (("row_steps", "row"), ("col_steps", "col")):
+        assert spans[0]["args"][arg] == sum(
+            sweep_steps(s) for s in data[key].reshape(2, -1)) == 2
     mf.layout(*np.nonzero(mask), A[mask > 0], 8)   # no recorder: no error
 
 
