@@ -1,34 +1,23 @@
 """Thin plan adapter for the app-level ``fit`` drivers.
 
-Every app exposes ``fit(..., plan=ExecutionPlan(...))``; the legacy
-``executor=``/``staleness=`` kwargs still work behind
-:func:`resolve_plan` (emitting a ``DeprecationWarning`` and producing a
-bit-identical run), and a bare ``trace_every=`` maps silently onto
-``collect_every`` (it stays the loop-path trace knob and does not warn
-on its own).  All executor-name/kwarg validation lives in
-:class:`repro.core.plan.ExecutionPlan` — the single source of truth the
-old ``scan_depth`` helper's drifted error message was folded into.
+Every app exposes ``fit(..., plan=ExecutionPlan(...))``; a bare
+``fit(num_rounds=, trace_every=)`` builds the host-loop plan (the
+trace cadence maps onto ``collect_every``).  All executor-name/kwarg
+validation lives in :class:`repro.core.plan.ExecutionPlan`.
 """
 from __future__ import annotations
 
-import warnings
-from typing import Any, Callable, List, Optional, Tuple
+from typing import List, Optional, Tuple
 
 from repro.core import ExecutionPlan
 
 
 def resolve_plan(plan: Optional[ExecutionPlan], *,
                  num_rounds: Optional[int] = None,
-                 executor: Optional[str] = None,
-                 staleness: Optional[int] = None,
                  trace_every: Optional[int] = None) -> ExecutionPlan:
-    """One plan out of either surface: the declarative ``plan=`` or the
-    deprecated per-kwarg form (which warns and builds the same plan, so
-    both run bit-identically through ``StradsEngine.execute``)."""
+    """The plan ``fit`` runs: ``plan=`` as given, or a host-loop plan of
+    ``num_rounds`` rounds tracing every ``trace_every``."""
     if plan is not None:
-        if executor is not None or staleness is not None:
-            raise ValueError("pass either plan= or the legacy executor=/"
-                             "staleness= kwargs, not both")
         if num_rounds is not None and num_rounds != plan.rounds:
             raise ValueError(f"num_rounds={num_rounds} contradicts "
                              f"plan.rounds={plan.rounds}; drop one")
@@ -42,33 +31,11 @@ def resolve_plan(plan: Optional[ExecutionPlan], *,
                 "drive StradsEngine.execute(..., ckpt_dir=...) directly "
                 "for those plan fields")
         return plan
-    if executor is not None or staleness is not None:
-        warnings.warn(
-            "fit(executor=..., staleness=...) is deprecated; pass "
-            "plan=ExecutionPlan(executor=..., staleness=..., rounds=...) "
-            "instead", DeprecationWarning, stacklevel=3)
     if num_rounds is None:
         raise ValueError("fit needs num_rounds (or a plan= carrying "
                          "rounds)")
-    return ExecutionPlan(executor=executor if executor is not None
-                         else "loop",
-                         rounds=num_rounds,
-                         staleness=staleness or 0,
+    return ExecutionPlan(executor="loop", rounds=num_rounds,
                          collect_every=trace_every or 0)
-
-
-def run_executor(eng, state, data, rng, num_rounds: int, executor: str,
-                 collect: Optional[Callable[[Any], Any]] = None,
-                 staleness: int = 0):
-    """Deprecated: build an :class:`ExecutionPlan` and call
-    ``StradsEngine.execute`` instead."""
-    warnings.warn("run_executor is deprecated; use StradsEngine.execute "
-                  "with an ExecutionPlan", DeprecationWarning,
-                  stacklevel=2)
-    plan = ExecutionPlan(executor=executor, rounds=num_rounds,
-                         staleness=staleness)
-    rep = eng.execute(state, data, rng, plan, collect=collect)
-    return rep.state if collect is None else (rep.state, rep.trace)
 
 
 def trace_points(num_rounds: int, trace_every: int) -> List[int]:

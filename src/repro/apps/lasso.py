@@ -272,7 +272,7 @@ class StradsLasso(StradsAppBase):
 
     def objective_collect(self):
         """Same objective as a global (non-shard_map) expression, usable as
-        a ``run_scanned`` collect fn inside the scan trace."""
+        an ``execute`` collect fn inside the scan trace."""
         lam = self.cfg.lam
         return lambda s: (0.5 * jnp.sum(s["r"] * s["r"])
                           + lam * jnp.sum(jnp.abs(s["beta"])))
@@ -317,8 +317,7 @@ def make_engine(cfg: LassoConfig, mesh,
 
 def fit(cfg: LassoConfig, X: np.ndarray, y: np.ndarray, mesh,
         num_rounds: Optional[int] = None, rng: Optional[jax.Array] = None,
-        trace_every: Optional[int] = None, executor: Optional[str] = None,
-        staleness: Optional[int] = None, plan=None):
+        trace_every: Optional[int] = None, plan=None):
     """Run STRADS Lasso; returns (state, trace of objective values).
 
     ``plan`` (an :class:`~repro.core.ExecutionPlan`) declares how to run:
@@ -327,12 +326,10 @@ def fit(cfg: LassoConfig, X: np.ndarray, y: np.ndarray, mesh,
     prefetch / ``"ssp"`` bounded staleness, at s=0 bit-identical to
     ``"scan"``), rounds, the ``collect_every`` trace cadence, and the
     scheduling policy (``plan.scheduler``, a ``SchedulerSpec`` — ``None``
-    runs the config's default policy).  The legacy
-    ``executor=``/``staleness=``/``trace_every=`` kwargs still work
-    (deprecated, bit-identical).
+    runs the config's default policy).  Without a plan, ``num_rounds``
+    and ``trace_every`` run the host loop.
     """
     plan = _exec.resolve_plan(plan, num_rounds=num_rounds,
-                              executor=executor, staleness=staleness,
                               trace_every=trace_every)
     rng = rng if rng is not None else jax.random.key(0)
     eng = make_engine(cfg, mesh)

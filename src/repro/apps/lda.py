@@ -455,7 +455,7 @@ def make_engine(cfg: LDAConfig, mesh, baseline: bool = False) -> StradsEngine:
 def _global_loglik(cfg: LDAConfig, state):
     """The collapsed log P(W, Z) as a plain global expression (equal to the
     shard_map reduction — psum of per-shard sums is the global sum), so it
-    can run as a ``run_scanned`` collect fn inside the scan."""
+    can run as an ``execute`` collect fn inside the scan."""
     lb = jnp.sum(gammaln(state["B"] + cfg.gamma))
     ld = jnp.sum(gammaln(state["D"] + cfg.alpha)) \
         - jnp.sum(gammaln(jnp.sum(state["D"], 1)
@@ -465,14 +465,11 @@ def _global_loglik(cfg: LDAConfig, state):
 
 
 def fit(cfg: LDAConfig, words, docs, z0, mesh, num_rounds=None,
-        baseline: bool = False, trace_every=None,
-        executor=None, staleness=None, plan=None):
-    """``plan``: an :class:`~repro.core.ExecutionPlan` (see lasso.fit;
-    legacy ``executor=``/``staleness=`` kwargs deprecated).  For
-    "pipelined"/"ssp", the rounds must tile the rotation length U (and
-    the SSP window)."""
+        baseline: bool = False, trace_every=None, plan=None):
+    """``plan``: an :class:`~repro.core.ExecutionPlan` (see lasso.fit).
+    For "pipelined"/"ssp", the rounds must tile the rotation length U
+    (and the SSP window)."""
     plan = _exec.resolve_plan(plan, num_rounds=num_rounds,
-                              executor=executor, staleness=staleness,
                               trace_every=trace_every)
     eng = make_engine(cfg, mesh, baseline=baseline)
     data = eng.shard_data({"words": jnp.asarray(words),

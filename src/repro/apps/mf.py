@@ -459,7 +459,7 @@ class StradsMF(StradsAppBase):
         return jax.jit(lambda s: fn(s["R"], s["W"], s["H"]))
 
     def objective_collect(self):
-        """Global-expression objective for ``run_scanned`` collect."""
+        """Global-expression objective for an ``execute`` collect fn."""
         lam = self.cfg.lam
         return lambda s: (jnp.sum(s["R"] * s["R"])
                           + lam * jnp.sum(s["W"] * s["W"])
@@ -655,14 +655,12 @@ def make_engine(cfg: MFConfig, mesh) -> StradsEngine:
 
 def fit(cfg: MFConfig, A: np.ndarray, mask: np.ndarray, mesh,
         num_rounds: Optional[int] = None, rng: Optional[jax.Array] = None,
-        trace_every=None, executor=None, staleness=None, plan=None):
-    """``plan``: an :class:`~repro.core.ExecutionPlan` (see lasso.fit;
-    legacy ``executor=``/``staleness=`` kwargs deprecated).  For
-    "pipelined"/"ssp", the rounds must divide into H/W phase cycles (and
-    SSP windows).  The dense ``(A, mask)`` becomes the sparse layout
-    once, on the host."""
+        trace_every=None, plan=None):
+    """``plan``: an :class:`~repro.core.ExecutionPlan` (see lasso.fit).
+    For "pipelined"/"ssp", the rounds must divide into H/W phase cycles
+    (and SSP windows).  The dense ``(A, mask)`` becomes the sparse
+    layout once, on the host."""
     plan = _exec.resolve_plan(plan, num_rounds=num_rounds,
-                              executor=executor, staleness=staleness,
                               trace_every=trace_every)
     rng = rng if rng is not None else jax.random.key(0)
     eng = make_engine(cfg, mesh)

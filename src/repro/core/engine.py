@@ -1,4 +1,4 @@
-"""The STRADS round executors: host loop, scanned, and pipelined.
+"""The STRADS round executors behind one entry point.
 
 Turns a :class:`~repro.core.primitives.StradsApp` into jitted programs
 executing
@@ -9,35 +9,36 @@ with ``push``/``schedule_stats`` running under ``shard_map`` over the
 ``data`` mesh axis and schedule decisions replicated.  ``sync`` is
 automatic: SPMD program order is the BSP barrier (DESIGN.md §3).
 
-The one public entry point is :meth:`StradsEngine.execute`, driven by a
-declarative :class:`~repro.core.plan.ExecutionPlan` (executor choice,
-rounds, staleness, unrolling, checkpoint cadence, **scheduling policy**
-— validated at plan construction) and returning a uniform
+:meth:`StradsEngine.execute` is the one way to run rounds.  It takes a
+declarative :class:`~repro.core.plan.ExecutionPlan` — rounds, unrolling,
+staleness, checkpoint cadence, scheduling policy, and the executor as a
+plain value, all validated at plan construction — and returns a uniform
 :class:`~repro.core.plan.ExecutionReport` (state, trace, telemetry,
-resumable carry).  Under it, four execution paths share one traced round
-body:
+resumable carry).  The executors share one traced round body:
 
-* :meth:`StradsEngine.run` — the host loop: one jitted round per
-  dispatch, a host↔device sync every round, arbitrary Python callbacks
-  between rounds.  The debugging/metrics path.
-* :meth:`StradsEngine.run_scanned` with ``pipeline_depth=0`` — rolls R
-  rounds into a single ``jax.lax.scan`` (one XLA program, donated state
-  buffers, zero per-round host round-trips).  Bit-identical to the host
-  loop: same PRNG stream, same op order.
-* ``pipeline_depth=1`` — the paper's pipelined scheduler: inside scan
-  step t the schedule for round t+1 is computed from the state *before*
-  round t's update, so it carries no data dependency on round t's
-  push/pull and XLA is free to overlap the two (software pipelining).
-  The schedule each round executes is therefore exactly one round stale
-  — the STRADS stale-schedule guarantee (Lee et al. 2014 §pipelining;
+* ``"loop"`` — one jitted round (:meth:`StradsEngine.run_round`) per
+  dispatch, a host↔device sync every round, a Python callback between
+  rounds.  The debugging/metrics path.
+* ``"scan"`` — R rounds rolled into a single ``jax.lax.scan`` (one XLA
+  program, donated state buffers, zero per-round host round-trips).
+  Bit-identical to the loop: same PRNG stream, same op order.
+* ``"pipelined"`` — the paper's pipelined scheduler: inside scan step t
+  the schedule for round t+1 is computed from the state *before* round
+  t's update, so it carries no data dependency on round t's push/pull
+  and XLA is free to overlap the two (software pipelining).  The
+  schedule each round executes is therefore exactly one round stale —
+  the STRADS stale-schedule guarantee (Lee et al. 2014 §pipelining;
   dynamic Lasso keeps converging because priorities c_j change slowly
   between adjacent rounds).
-* :meth:`StradsEngine.run_ssp` — the bounded-staleness (SSP) executor,
-  implemented by the parameter-server subsystem in :mod:`repro.ps`:
-  reads of replicated state served from worker caches up to s rounds
-  old, pushes aggregated lazily into one batched flush collective per
-  s+1-round window.  ``staleness=0`` is bit-identical to
-  ``run_scanned(pipeline_depth=0)``.
+* ``"ssp"`` — bounded staleness, implemented by the parameter-server
+  subsystem in :mod:`repro.ps`: reads of replicated state served from
+  worker caches up to s rounds old, pushes aggregated lazily into one
+  batched flush collective per s+1-round window.  ``staleness=0`` is
+  bit-identical to ``"scan"``.
+
+:meth:`StradsEngine.scanned_fn` and :meth:`StradsEngine.ssp_fn` hand out
+the compiled multi-round programs themselves, for AOT
+``.lower().compile()``.
 
 Apps whose communication pattern cycles with period L (``phase_period``,
 e.g. LDA's rotation over U workers, MF's H/W alternation) get L rounds
@@ -713,16 +714,16 @@ class StradsEngine:
                 lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
                 data, self.data_specs)
 
-    # -- execution: host loop ------------------------------------------------
+    # -- execution: the round and the compiled programs --------------------
 
     def run_round(self, state, data, rng, t: int = 0,
                   sched_carry: Any = _UNSET) -> RoundResult:
-        """One jitted BSP round.  ``sched_carry`` defaults to a fresh
-        ``scheduler.init_carry()``; thread ``result.sched_carry`` back in
-        to keep a stateful policy's priorities evolving across rounds
-        (omitting it at t > 0 warns — the priorities silently reset to
-        uniform, which is almost never what a round loop wants; use
-        :meth:`run`/:meth:`execute` for whole runs)."""
+        """One jitted BSP round — the loop executor's body.
+        ``sched_carry`` defaults to a fresh ``scheduler.init_carry()``;
+        thread ``result.sched_carry`` back in to keep a stateful policy's
+        priorities evolving across rounds (omitting it at t > 0 warns —
+        the priorities silently reset to uniform, which is almost never
+        what a round loop wants; use :meth:`execute` for whole runs)."""
         phase = self.app.static_phase(t)
         if sched_carry is _UNSET:
             sched_carry = self.init_sched_carry()
@@ -731,163 +732,18 @@ class StradsEngine:
                     "run_round(t>0) without sched_carry reinitializes "
                     "the stateful scheduler's priorities every call; "
                     "thread result.sched_carry between rounds (or drive "
-                    "the run through run/execute)", UserWarning,
+                    "the run through execute)", UserWarning,
                     stacklevel=2)
         return self._round(state, sched_carry, data, rng, phase,
                            jnp.int32(t))
-
-    def run(self, state, data, rng, num_rounds: int, callback=None):
-        """Drive ``num_rounds`` BSP rounds (host loop; each round jitted).
-
-        ``callback(t, state, result)`` runs between rounds (metrics, early
-        stop by returning True).  Zero (or negative) rounds are a no-op —
-        the zero-round escape hatch ``run_scanned`` points callers at.
-        One implementation with the plan path: this is exactly
-        ``execute(plan(executor="loop"))``."""
-        if num_rounds < 1:
-            return state
-        plan = ExecutionPlan(executor="loop", rounds=num_rounds)
-        # execute-equivalence includes the policies: re-resolve the
-        # default specs so a scheduler, partitioner, or kernel backend
-        # swept in by a previous execute(plan.…=...) cannot leak into
-        # this run
-        self.set_scheduler(None)
-        self.set_partitioner(None)
-        self.set_kernels(None)
-        self.reset_partition()
-        return self._execute_span(state, data, rng, plan, num_rounds, 0,
-                                  None, None, callback).state
-
-    # -- execution: scanned / pipelined --------------------------------------
-
-    def run_scanned(self, state, data, rng, num_rounds: int, *,
-                    pipeline_depth: int = 0,
-                    collect: Optional[Callable[[Any], Any]] = None,
-                    donate: bool = True, unroll: int = 1,
-                    t0: int = 0, sched0: Any = None,
-                    sched_carry0: Any = _UNSET, obs0: Any = None,
-                    return_carry: bool = False):
-        """Execute ``num_rounds`` rounds as one XLA program.
-
-        ``pipeline_depth=0`` reproduces :meth:`run` bit-for-bit (same PRNG
-        stream, fresh schedules).  ``pipeline_depth=1`` software-pipelines
-        the scheduler one round ahead (see module docstring); round t then
-        executes the schedule computed from the state after round t−2 —
-        the paper's one-round schedule staleness.  The round-t schedule
-        uses the *same* PRNG key in both modes, so depth-1 differs from
-        depth-0 only through staleness, never through a different random
-        stream.
-
-        ``collect(state) -> pytree`` is evaluated after every round inside
-        the scan; the stacked results (leading axis ``num_rounds``) are
-        returned as the trace without any per-round host sync.
-
-        ``donate=True`` donates the state buffers to the XLA program (the
-        caller's ``state`` is consumed); pass ``donate=False`` when the
-        input state must stay alive (e.g. A/B comparisons in tests).
-
-        ``unroll`` widens a scan step to ``unroll`` phase cycles
-        (``ExecutionPlan.phase_unroll``): the same round sequence chunked
-        ``unroll × phase_period`` rounds per step — bit-identical, fewer
-        scan iterations.
-
-        ``t0``/``sched0``/``sched_carry0`` resume a previous run (pass
-        the values from an :class:`EngineCarry`; ``t0`` must be a
-        multiple of the phase period, ``sched0`` is only meaningful at
-        depth 1 where it is the prefetched in-flight schedule, and
-        ``sched_carry0`` is the scheduler carry — omitted, a fresh
-        ``scheduler.init_carry()`` is used, which is only correct at
-        ``t0=0``).  ``obs0`` threads the device telemetry counters
-        (:func:`repro.obs.counters.init_counters`, or the previous
-        carry's ``obs``) through the scan; ``None`` runs
-        uninstrumented.  ``return_carry=True`` appends the final carry
-        to the return value.
-
-        Returns ``state`` (plus ``trace`` when collecting, plus ``carry``
-        when requested).
-        """
-        if pipeline_depth not in (0, 1):
-            raise ValueError(f"pipeline_depth must be 0 or 1, got "
-                             f"{pipeline_depth}")
-        if num_rounds < 1:
-            raise ValueError("run_scanned needs num_rounds >= 1 (use the "
-                             "host loop `run` for zero-round calls)")
-        if unroll < 1:
-            raise ValueError(f"unroll must be >= 1, got {unroll}")
-        period = self.phase_period
-        if t0 % period:
-            raise ValueError(f"t0 must be a multiple of the phase period "
-                             f"({period}) so phases stay static; got {t0}")
-        if sched0 is not None and pipeline_depth != 1:
-            raise ValueError("sched0 only resumes the pipelined executor "
-                             "(pipeline_depth=1)")
-        if sched_carry0 is _UNSET:
-            sched_carry0 = self.init_sched_carry()
-            if t0 and sched_carry0 is not None:
-                warnings.warn(
-                    "run_scanned(t0>0) without sched_carry0 "
-                    "reinitializes the stateful scheduler's priorities; "
-                    "pass the EngineCarry.sched_carry a previous run "
-                    "returned for a bit-exact resume", UserWarning,
-                    stacklevel=2)
-        L = period * unroll
-        num_steps, tail = divmod(num_rounds, L)
-        if tail and pipeline_depth == 1:
-            raise ValueError(
-                f"pipeline_depth=1 needs num_rounds divisible by the app's "
-                f"phase_period ({period}) × unroll ({unroll}); got "
-                f"{num_rounds}")
-
-        traces = []
-        sched_c = sched0
-        sc = sched_carry0
-        obs = obs0
-        if num_steps:
-            with self._obs_span("scan.program"):
-                fn = self._get_scan_fn(num_steps, pipeline_depth, collect,
-                                       donate, unroll, sched0 is not None)
-            with self._obs_span("scan.replicate"):
-                rng, sc, obs = self.replicate((rng, sc, obs))
-                args = (state, data, rng, jnp.int32(t0), sc, obs)
-                if sched0 is not None:
-                    args += (self.replicate(sched0),)
-            with self._obs_span("scan.call"):
-                state, rng, sched_c, sc, obs, ys = fn(*args)
-            if collect is not None:
-                traces.append(ys)
-
-        # Remainder rounds (num_rounds % (period × unroll)) fall back to
-        # the host loop with fresh schedules — only reachable at depth 0.
-        num_cand = self._obs_num_candidates()
-        for k in range(tail):
-            t = t0 + num_steps * L + k
-            rng, sub = jax.random.split(rng)
-            out = self.run_round(state, data, sub, t, sched_carry=sc)
-            if obs is not None:
-                obs = self._observe(obs, data, out.sched, t % period,
-                                    num_cand)
-            state, sc = out.state, out.sched_carry
-            if collect is not None:
-                traces.append(jax.tree.map(
-                    lambda x: jnp.asarray(x)[None], collect(state)))
-
-        ret = [state]
-        if collect is not None:
-            ret.append(jax.tree.map(lambda *xs: jnp.concatenate(xs),
-                                    *traces)
-                       if len(traces) > 1 else traces[0])
-        if return_carry:
-            ret.append(EngineCarry(rng=rng, t=jnp.int32(t0 + num_rounds),
-                                   sched=sched_c, sched_carry=sc,
-                                   obs=obs))
-        return ret[0] if len(ret) == 1 else tuple(ret)
 
     def scanned_fn(self, num_rounds: int, *, pipeline_depth: int = 0,
                    collect: Optional[Callable] = None,
                    donate: bool = True, unroll: int = 1):
         """The jitted ``(state, data, rng, t0, sched_carry, obs) →
         (state, rng, sched, sched_carry, obs, trace)`` multi-round
-        program, exposed for AOT ``.lower().compile()`` (the
+        program of the scan (``pipeline_depth=0``) or pipelined (``1``)
+        executor, exposed for AOT ``.lower().compile()`` (the
         production-mesh dry-run in ``launch/dryrun.py``; pass
         ``engine.init_sched_carry()`` for a fresh run and ``None`` —
         or ``repro.obs.init_counters(engine.phase_period)`` — for
@@ -907,19 +763,6 @@ class StradsEngine:
                                               collect, donate, unroll,
                                               False))
 
-    # -- execution: SSP (bounded staleness — repro.ps) -----------------------
-
-    def run_ssp(self, state, data, rng, num_rounds: int, *,
-                staleness: int = 0, **kw):
-        """The bounded-staleness executor (see :mod:`repro.ps.ssp`):
-        reads of replicated state served from worker caches up to
-        ``staleness`` rounds old, pushes aggregated lazily at the flush.
-        ``staleness=0`` is bit-identical to
-        ``run_scanned(pipeline_depth=0)``."""
-        from ..ps.ssp import run_ssp
-        return run_ssp(self, state, data, rng, num_rounds,
-                       staleness=staleness, **kw)
-
     def ssp_fn(self, num_rounds: int, *, staleness: int = 0,
                collect: Optional[Callable] = None, donate: bool = True):
         """The jitted multi-round SSP program, exposed for AOT
@@ -930,7 +773,7 @@ class StradsEngine:
                             ssp_fn(self, num_rounds, staleness=staleness,
                                    collect=collect, donate=donate))
 
-    # -- execution: the unified entry point ----------------------------------
+    # -- execution: the one entry point ---------------------------------------
 
     def execute(self, state, data, rng, plan: ExecutionPlan, *,
                 collect: Optional[Callable[[Any], Any]] = None,
@@ -939,10 +782,9 @@ class StradsEngine:
                 partition: Optional[dict] = None,
                 stream=None, source=None,
                 stream_state: Optional[dict] = None) -> ExecutionReport:
-        """Run an :class:`~repro.core.plan.ExecutionPlan` — the one entry
-        point that subsumes :meth:`run`, :meth:`run_scanned` and
-        :meth:`run_ssp` and returns a uniform
-        :class:`~repro.core.plan.ExecutionReport`.
+        """Run an :class:`~repro.core.plan.ExecutionPlan` — the one way
+        to run rounds, whichever executor the plan names — and return a
+        uniform :class:`~repro.core.plan.ExecutionReport`.
 
         ``plan.scheduler`` (a :class:`~repro.sched.spec.SchedulerSpec`)
         selects the scheduling policy; ``None`` resolves to the app's
@@ -993,8 +835,7 @@ class StradsEngine:
         """
         if not isinstance(plan, ExecutionPlan):
             raise TypeError(f"execute() wants an ExecutionPlan; got "
-                            f"{type(plan).__name__} (legacy executor= "
-                            f"kwargs live behind the app-level fit shims)")
+                            f"{type(plan).__name__}")
         # telemetry (the telemetry-injection contract): the resolved
         # TelemetrySpec turns on device counters for every executor;
         # kind="trace" records this call's spans and events (cache
@@ -1013,8 +854,8 @@ class StradsEngine:
                                 rounds=plan.rounds):
                 with self._obs_span("execute.resolve"):
                     t_done, rng, chunk, pspec, ingestor = self._resolve(
-                        plan, rng, carry, callback, ckpt_dir, partition,
-                        stream, source, stream_state, data)
+                        plan, state, data, rng, carry, callback, ckpt_dir,
+                        partition, stream, source, stream_state)
                 rep = self._execute_plan(state, data, rng, plan, t_done,
                                          carry, collect, callback, chunk,
                                          pspec, ckpt_dir, ingestor)
@@ -1023,13 +864,12 @@ class StradsEngine:
         if tspec is None:
             rep.telemetry = None
             return rep
-        ssp_parts = rep.telemetry if isinstance(rep.telemetry, list) \
-            else ([rep.telemetry] if rep.telemetry is not None else [])
-        if len(ssp_parts) > 1:
+        parts = rep.telemetry           # per-chunk SSP summaries
+        if len(parts) > 1:
             from ..ps.telemetry import merge_summaries
-            ssp = merge_summaries(ssp_parts)
+            ssp = merge_summaries(parts)
         else:
-            ssp = ssp_parts[0] if ssp_parts else None
+            ssp = parts[0] if parts else None
         # the host's round index: every executor runs the plan to its end
         # but a host loop a callback stopped (whose carry.t is a host int)
         rounds = (int(rep.carry.t) if callback is not None
@@ -1040,8 +880,9 @@ class StradsEngine:
             events=events)
         return rep
 
-    def _resolve(self, plan: ExecutionPlan, rng, carry, callback, ckpt_dir,
-                 partition, stream, source, stream_state, data):
+    def _resolve(self, plan: ExecutionPlan, state, data, rng, carry,
+                 callback, ckpt_dir, partition, stream, source,
+                 stream_state):
         """``execute``'s checks and policy resolution before any round
         runs: the set_* calls, the carry checks, the checkpoint and
         stream arguments.  Returns ``(t_done, rng, chunk, pspec,
@@ -1073,11 +914,10 @@ class StradsEngine:
                 raise ValueError("resuming a scanned plan needs the "
                                  "EngineCarry a previous scan/pipelined "
                                  "report returned")
-            if plan.executor == "pipelined" and carry.sched is None:
-                raise ValueError("resuming a pipelined plan needs the "
-                                 "carried in-flight schedule (carry.sched "
-                                 "is None — was this carry produced by a "
-                                 "different executor?)")
+            if plan.executor == "scan" and carry.sched is not None:
+                raise ValueError("carry.sched holds a pipelined run's "
+                                 "in-flight schedule; resume it with "
+                                 "executor='pipelined'")
             stateful = self.init_sched_carry() is not None
             prev_sc = getattr(carry, "sched_carry", None)
             if stateful and prev_sc is None:
@@ -1091,10 +931,25 @@ class StradsEngine:
                     "history, but the plan's resolved policy is "
                     "stateless — the SchedulerSpec must match across "
                     "resume")
+            if plan.executor == "pipelined" and carry.sched is None \
+                    and not self._schedules_nothing(state, data, prev_sc):
+                raise ValueError("resuming a pipelined plan needs the "
+                                 "carried in-flight schedule (carry.sched "
+                                 "is None — was this carry produced by a "
+                                 "different executor?)")
             t_done = int(carry.t)
             if not 0 <= t_done < plan.rounds:
                 raise ValueError(f"carry.t={t_done} leaves no rounds of "
                                  f"the plan's {plan.rounds} to run")
+            # a resumed run starts on a phase boundary (and, under ssp, a
+            # window boundary), so every phase stays a static int: the
+            # step length less the unrolling
+            align = self._step_length(plan) // plan.phase_unroll
+            if t_done % align:
+                raise ValueError(
+                    f"carry.t={t_done} is not a multiple of {align}: the "
+                    f"{plan.executor!r} executor starts each span at a t0 "
+                    f"on a phase/window boundary")
             rng = carry.rng
 
         if ckpt_dir and not plan.checkpoint_every:
@@ -1135,10 +990,20 @@ class StradsEngine:
                       pspec, ckpt_dir, ingestor=None) -> ExecutionReport:
         """The executor dispatch of :meth:`execute` — whole-plan, or the
         boundary-chunked loop (checkpoint cadence, ingest cadence, or
-        their gcd when both are active).  Under an ssp plan the
-        returned report's ``telemetry`` holds the raw per-chunk
-        :class:`~repro.ps.telemetry.SSPTelemetry` (a list when chunked);
-        ``execute`` merges it into the final :class:`RunReport`."""
+        their gcd when both are active).  The returned report's
+        ``telemetry`` is the list of per-chunk
+        :class:`~repro.ps.telemetry.SSPTelemetry` summaries (empty off
+        ssp or uninstrumented); ``execute`` merges them into the final
+        :class:`RunReport`."""
+        step_len = self._step_length(plan)
+        left = plan.rounds - t_done
+        if plan.executor in ("pipelined", "ssp") and left % step_len:
+            # fail before any chunk runs or checkpoint is written
+            raise ValueError(
+                f"plan.rounds={plan.rounds} leaves {left} rounds from "
+                f"round {t_done}, not divisible by the {plan.executor!r} "
+                f"executor's step length {step_len}: it runs whole steps "
+                f"only, so those rounds must be a multiple of {step_len}")
         ing_every = ingestor.spec.ingest_every if ingestor is not None \
             else 0
         if not chunk and not ing_every:
@@ -1149,10 +1014,12 @@ class StradsEngine:
                     "checkpoint_every + ckpt_dir the assignment stays "
                     "at its initial (static) value for the whole run",
                     UserWarning, stacklevel=3)
-            return self._execute_span(state, data, rng, plan,
-                                      plan.rounds - t_done, t_done, carry,
-                                      collect, callback)
-        step_len = self._step_length(plan)
+            state, trace, telem, carry = self._execute_span(
+                state, data, rng, plan, left, t_done, carry, collect,
+                callback)
+            return ExecutionReport(state=state, trace=trace,
+                                   telemetry=[] if telem is None else [telem],
+                                   carry=carry, plan=plan)
         if chunk and chunk % step_len:
             raise ValueError(
                 f"plan.checkpoint_every={chunk} must be a multiple of the "
@@ -1165,13 +1032,6 @@ class StradsEngine:
                 f"the {plan.executor!r} executor's step length {step_len} "
                 f"(phase/window alignment), so every ingest boundary is "
                 f"host-synced")
-        if plan.executor in ("pipelined", "ssp") and plan.rounds % step_len:
-            # fail before any chunk runs — the same plan without ckpt_dir
-            # is rejected upfront by the executor itself
-            raise ValueError(
-                f"plan.rounds={plan.rounds} must be a multiple of the "
-                f"{plan.executor!r} executor's step length {step_len}; "
-                f"the final checkpoint chunk would be unrunnable")
         # with both cadences active, spans run boundary to boundary; the
         # plain checkpointed run keeps span == chunk exactly as before
         span = (math.gcd(chunk, ing_every) if chunk and ing_every
@@ -1202,14 +1062,13 @@ class StradsEngine:
                 # one did
                 state, data = ingestor.step(self, state, data, t)
             n = min(span, plan.rounds - t)
-            rep = self._execute_span(state, data, rng, plan, n, t, carry,
-                                     collect, cb)
-            state, carry = rep.state, rep.carry
+            state, trace, telem, carry = self._execute_span(
+                state, data, rng, plan, n, t, carry, collect, cb)
             rng = carry.rng
-            if rep.trace is not None:
-                traces.append(rep.trace)
-            if rep.telemetry is not None:
-                ssp_parts.append(rep.telemetry)
+            if trace is not None:
+                traces.append(trace)
+            if telem is not None:
+                ssp_parts.append(telem)
             t = int(carry.t)
             at_chunk = (not chunk or t % chunk == 0 or t >= plan.rounds
                         or bool(stops))
@@ -1234,10 +1093,20 @@ class StradsEngine:
         trace = (jax.tree.map(lambda *xs: jnp.concatenate(xs), *traces)
                  if traces else None)
         return ExecutionReport(state=state, trace=trace,
-                               telemetry=ssp_parts or None,
+                               telemetry=ssp_parts,
                                carry=carry, plan=plan,
                                stream=(ingestor.payload()
                                        if ingestor is not None else None))
+
+    def _schedules_nothing(self, state, data, sched_carry) -> bool:
+        """Whether the app's schedule is an empty pytree (LDA's rotation
+        schedules by phase alone), so a pipelined run carries no
+        in-flight schedule.  Traced abstractly: nothing runs."""
+        shape = jax.eval_shape(
+            lambda s, c, d: self._make_schedule(
+                s, c, d, jax.random.key(0), jnp.int32(0), 0),
+            state, sched_carry, data)
+        return not jax.tree.leaves(shape)
 
     def _step_length(self, plan: ExecutionPlan) -> int:
         """Rounds one compiled step of the plan's executor covers — the
@@ -1253,87 +1122,109 @@ class StradsEngine:
         return 1                                # loop: any round
 
     def _execute_span(self, state, data, rng, plan: ExecutionPlan,
-                      rounds: int, t0: int, prev_carry, collect,
-                      callback) -> ExecutionReport:
+                      rounds: int, t0: int, prev_carry, collect, callback):
         """One contiguous span of a plan (the whole plan, or one
-        checkpoint chunk), dispatched to the executor it names."""
-        sc0 = (prev_carry.sched_carry if prev_carry is not None
-               else self.init_sched_carry())
+        checkpoint chunk), run by the executor it names.  ``execute``
+        has validated the plan, ``t0`` and ``prev_carry`` already.
+        Returns ``(state, trace, telemetry, carry)``: ``telemetry`` is
+        the span's SSP summary, ``None`` off ssp or uninstrumented."""
+        sc = (prev_carry.sched_carry if prev_carry is not None
+              else self.init_sched_carry())
         # device counters: resume the previous chunk's (bit-exact through
         # checkpoint_every chunking), else start fresh when the plan is
         # instrumented; None runs uninstrumented
-        obs0 = getattr(prev_carry, "obs", None)
-        if obs0 is None and plan.telemetry:
-            obs0 = obs_counters.init_counters(self.phase_period)
+        obs = getattr(prev_carry, "obs", None)
+        if obs is None and plan.telemetry:
+            obs = obs_counters.init_counters(self.phase_period)
         if plan.executor == "loop":
-            cfn = None
-            if collect is not None:
-                # cached so checkpoint-chunked loop runs compile it once
-                key = ("loop_collect", collect)
-                cfn = self._scan_cache.get(key)
-                if cfn is None:
-                    cfn = jax.jit(collect)
-                    self._scan_cache[key] = cfn
-            ys: list = []
-            executed = 0
-            sc = sc0
-            obs = obs0
-            num_cand = self._obs_num_candidates()
-            period = self.phase_period
             with self._obs_span("loop", t0=t0, rounds=rounds):
-                for k in range(rounds):
-                    t = t0 + k
-                    rng, sub = jax.random.split(rng)
-                    out = self.run_round(state, data, sub, t,
-                                         sched_carry=sc)
-                    state, sc = out.state, out.sched_carry
-                    if obs is not None:
-                        obs = self._observe(obs, data, out.sched,
-                                            t % period, num_cand)
-                    executed = k + 1
-                    if cfn is not None:
-                        ys.append(cfn(state))
-                    if callback is not None and callback(t, state, out):
-                        break
-            trace = (jax.tree.map(lambda *xs: jnp.stack(xs), *ys)
-                     if ys else None)
-            carry = EngineCarry(rng=rng, t=jnp.int32(t0 + executed),
-                                sched_carry=sc, obs=obs)
-            return ExecutionReport(state=state, trace=trace,
-                                   carry=carry, plan=plan)
+                return self._run_loop(state, data, rng, rounds, t0, sc,
+                                      obs, collect, callback)
+        if plan.executor == "ssp":
+            from ..ps.ssp import run_span
+            with self._obs_span("ssp", t0=t0, rounds=rounds,
+                                staleness=plan.staleness):
+                return run_span(self, state, data, rng, plan, rounds, t0,
+                                getattr(prev_carry, "clocks", None), sc,
+                                obs, collect)
+        with self._obs_span(plan.executor, t0=t0, rounds=rounds):
+            return self._run_scan(state, data, rng, plan, rounds, t0,
+                                  getattr(prev_carry, "sched", None), sc,
+                                  obs, collect)
 
-        if plan.executor in ("scan", "pipelined"):
-            sched0 = getattr(prev_carry, "sched", None)
-            with self._obs_span(plan.executor, t0=t0, rounds=rounds):
-                out = self.run_scanned(
-                    state, data, rng, rounds, pipeline_depth=plan.depth,
-                    collect=collect, donate=plan.donate,
-                    unroll=plan.phase_unroll, t0=t0, sched0=sched0,
-                    sched_carry0=sc0, obs0=obs0, return_carry=True)
-            if collect is None:
-                state, carry = out
-                trace = None
-            else:
-                state, trace, carry = out
-            return ExecutionReport(state=state, trace=trace,
-                                   carry=carry, plan=plan)
+    def _run_loop(self, state, data, rng, rounds: int, t0: int, sc, obs,
+                  collect, callback):
+        """The loop executor: one :meth:`run_round` dispatch a round,
+        ``callback(t, state, result)`` after each (True stops)."""
+        cfn = None
+        if collect is not None:
+            # cached so checkpoint-chunked loop runs compile it once
+            key = ("loop_collect", collect)
+            cfn = self._scan_cache.get(key)
+            if cfn is None:
+                cfn = jax.jit(collect)
+                self._scan_cache[key] = cfn
+        ys: list = []
+        executed = 0
+        num_cand = self._obs_num_candidates()
+        period = self.phase_period
+        for k in range(rounds):
+            t = t0 + k
+            rng, sub = jax.random.split(rng)
+            out = self.run_round(state, data, sub, t, sched_carry=sc)
+            state, sc = out.state, out.sched_carry
+            if obs is not None:
+                obs = self._observe(obs, data, out.sched, t % period,
+                                    num_cand)
+            executed = k + 1
+            if cfn is not None:
+                ys.append(cfn(state))
+            if callback is not None and callback(t, state, out):
+                break
+        trace = _stack_rounds(ys) if ys else None
+        carry = EngineCarry(rng=rng, t=jnp.int32(t0 + executed),
+                            sched_carry=sc, obs=obs)
+        return state, trace, None, carry
 
-        # executor == "ssp" (plan validation admits nothing else)
-        clocks = getattr(prev_carry, "clocks", None)
-        with self._obs_span("ssp", t0=t0, rounds=rounds,
-                            staleness=plan.staleness):
-            out = self.run_ssp(
-                state, data, rng, rounds, staleness=plan.staleness,
-                collect=collect, donate=plan.donate,
-                with_telemetry=bool(plan.telemetry), t0=t0, clocks=clocks,
-                sched_carry0=sc0, obs0=obs0, return_carry=True)
-        parts = list(out if isinstance(out, tuple) else (out,))
-        state = parts.pop(0)
-        trace = parts.pop(0) if collect is not None else None
-        telem = parts.pop(0) if plan.telemetry else None
-        carry = parts.pop(0)
-        return ExecutionReport(state=state, trace=trace, telemetry=telem,
-                               carry=carry, plan=plan)
+    def _run_scan(self, state, data, rng, plan: ExecutionPlan, rounds: int,
+                  t0: int, sched, sc, obs, collect):
+        """The scan and pipelined executors: whole steps of
+        ``phase_period × phase_unroll`` rounds as one compiled program
+        (``sched`` is a resumed pipelined run's in-flight schedule), then
+        — scan only — the rounds left over through the loop executor."""
+        L = self.phase_period * plan.phase_unroll
+        num_steps, tail = divmod(rounds, L)
+        traces = []
+        if num_steps:
+            with self._obs_span("scan.program"):
+                fn = self._get_scan_fn(num_steps, plan.depth, collect,
+                                       plan.donate, plan.phase_unroll,
+                                       sched is not None)
+            with self._obs_span("scan.replicate"):
+                rng, sc, obs = self.replicate((rng, sc, obs))
+                args = (state, data, rng, jnp.int32(t0), sc, obs)
+                if sched is not None:
+                    args += (self.replicate(sched),)
+            with self._obs_span("scan.call"):
+                state, rng, sched, sc, obs, ys = fn(*args)
+            if collect is not None:
+                traces.append(ys)
+
+        if tail:
+            state, ys, _, last = self._run_loop(
+                state, data, rng, tail, t0 + num_steps * L, sc, obs,
+                collect, None)
+            rng, sc, obs = last.rng, last.sched_carry, last.obs
+            if collect is not None:
+                traces.append(ys)
+
+        trace = None
+        if collect is not None:
+            trace = (jax.tree.map(lambda *xs: jnp.concatenate(xs), *traces)
+                     if len(traces) > 1 else traces[0])
+        carry = EngineCarry(rng=rng, t=jnp.int32(t0 + rounds), sched=sched,
+                            sched_carry=sc, obs=obs)
+        return state, trace, None, carry
 
     def _get_scan_fn(self, num_steps: int, depth: int,
                      collect: Optional[Callable], donate: bool,

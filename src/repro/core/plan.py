@@ -5,9 +5,8 @@ The paper's pitch is a *small, fixed* primitive set (``schedule`` /
 while the runtime freely swaps partitioning and update scheduling.  After
 the executor zoo grew (host loop, scanned, pipelined, SSP) the call
 surface no longer matched that pitch: every entry point had its own
-kwargs, and validation ("staleness needs ssp", "pipeline_depth needs
-num_rounds divisible by the phase period") was scattered across call
-sites.
+kwargs, and validation ("staleness needs ssp", "pipelined needs rounds
+divisible by the phase period") was scattered across call sites.
 
 An :class:`ExecutionPlan` is the single declarative answer:
 
@@ -17,8 +16,8 @@ An :class:`ExecutionPlan` is the single declarative answer:
   the error text lives in exactly one place;
 * **JSON-round-trippable** — ``to_json``/``from_json`` are exact
   (defaults included), so plans live in checked-in files
-  (``examples/plans/``), benchmark records (``BENCH_*.json``) and CLI
-  flags (``launch/train.py --plan``, ``launch/dryrun.py --plan``).
+  (``examples/plans/``) and CLI flags (``launch/train.py --plan``,
+  ``launch/dryrun.py --plan``).
 
 One engine entry point consumes it — ``StradsEngine.execute(state, data,
 rng, plan)`` — and returns a uniform :class:`ExecutionReport` (final
@@ -29,7 +28,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import warnings
 from typing import Any, Optional, Union
 
 from ..kernels.spec import KernelSpec
@@ -59,9 +57,6 @@ class ExecutionPlan:
     rounds:          total BSP/SSP rounds the plan executes.
     staleness:       SSP bound ``s`` (reads ≤ s rounds stale); > 0 only
                      valid with ``executor="ssp"``.
-    pipeline_depth:  explicit schedule-prefetch depth.  ``None`` derives
-                     it from the executor (scan→0, pipelined→1); a
-                     nonzero value requires ``executor="pipelined"``.
     phase_unroll:    rounds unrolled per scan step, as a multiple of the
                      app's ``phase_period`` (1 = one phase cycle per scan
                      step — the default and the bit-identical baseline).
@@ -75,9 +70,7 @@ class ExecutionPlan:
                      ``ExecutionReport.telemetry`` (device counters,
                      host events under ``kind="trace"``, and the SSP
                      staleness/byte section for ssp plans) — final model
-                     state stays bit-identical either way.  The
-                     deprecated bool form still works: ``True`` warns
-                     and normalizes to ``TelemetrySpec(kind="counters")``.
+                     state stays bit-identical either way.
     checkpoint_every: checkpoint cadence in rounds for
                      ``StradsEngine.execute(..., ckpt_dir=...)`` (0 = no
                      checkpointing); must tile the executor's step length.
@@ -127,7 +120,6 @@ class ExecutionPlan:
     executor: str = "scan"
     rounds: int = 1
     staleness: int = 0
-    pipeline_depth: Optional[int] = None
     phase_unroll: int = 1
     telemetry: Union[bool, TelemetrySpec] = False
     checkpoint_every: int = 0
@@ -151,17 +143,6 @@ class ExecutionPlan:
             raise ValueError(
                 f"staleness={self.staleness} requires executor='ssp'; got "
                 f"executor={self.executor!r}")
-        if self.pipeline_depth is not None:
-            if self.pipeline_depth not in (0, 1):
-                raise ValueError(f"pipeline_depth must be 0 or 1, got "
-                                 f"{self.pipeline_depth}")
-            if self.pipeline_depth > 0 and self.executor != "pipelined":
-                raise ValueError(
-                    f"pipeline_depth={self.pipeline_depth} requires "
-                    f"executor='pipelined'; got {self.executor!r}")
-            if self.pipeline_depth == 0 and self.executor == "pipelined":
-                raise ValueError("executor='pipelined' means "
-                                 "pipeline_depth=1; leave it None or pass 1")
         if not isinstance(self.phase_unroll, int) or self.phase_unroll < 1:
             raise ValueError(f"phase_unroll must be a positive int; got "
                              f"{self.phase_unroll!r}")
@@ -170,27 +151,15 @@ class ExecutionPlan:
             raise ValueError(
                 f"phase_unroll={self.phase_unroll} only applies to the "
                 f"scanned executors; got executor={self.executor!r}")
-        # telemetry graduated from a bool to a TelemetrySpec; True used
-        # to raise off-ssp ("telemetry=True requires executor='ssp'") —
-        # now every executor carries engine-wide counters, so the bool
-        # form only warns and normalizes onto the spec it implies.
+        # False (or None) is off; instrumentation is always a spec
         if self.telemetry is None:
             object.__setattr__(self, "telemetry", False)
-        if isinstance(self.telemetry, bool):
-            if self.telemetry:
-                warnings.warn(
-                    "plan.telemetry=True (bool) is deprecated; pass a "
-                    "repro.obs.TelemetrySpec — it no longer requires "
-                    "executor='ssp' (True maps to kind='counters', the "
-                    "engine-wide device counters, on every executor)",
-                    DeprecationWarning, stacklevel=3)
-                object.__setattr__(self, "telemetry",
-                                   TelemetrySpec(kind="counters"))
-        elif not isinstance(self.telemetry, TelemetrySpec):
+        if self.telemetry is not False \
+                and not isinstance(self.telemetry, TelemetrySpec):
             raise ValueError(
-                f"telemetry must be a bool or a repro.obs.TelemetrySpec "
-                f"(its own __post_init__ validates the kind); got "
-                f"{type(self.telemetry).__name__}")
+                f"telemetry must be False (off) or a repro.obs."
+                f"TelemetrySpec (its own __post_init__ validates the "
+                f"kind); got {self.telemetry!r}")
         for field in ("checkpoint_every", "collect_every"):
             v = getattr(self, field)
             if not isinstance(v, int) or v < 0:
@@ -225,8 +194,6 @@ class ExecutionPlan:
     @property
     def depth(self) -> int:
         """The schedule-prefetch depth this plan's executor runs at."""
-        if self.pipeline_depth is not None:
-            return self.pipeline_depth
         return 1 if self.executor == "pipelined" else 0
 
     # -- serialization -------------------------------------------------------

@@ -137,21 +137,19 @@ traced round lowers to.  The discipline it shares with the other two:
   kernels yet, so only ``"reference"`` applies) — the engine rejects a
   plan naming an unlisted kind at injection time, never at trace time;
 * compiled-program caches are keyed per (SchedulerSpec, Assignment,
-  KernelSpec), so a backend sweep — ``BENCH_kernels``'s reference vs
-  pallas arms — reuses each configuration's programs instead of
-  retracing on every swap.
+  KernelSpec), so a backend sweep — reference vs pallas — reuses each
+  configuration's programs instead of retracing on every swap.
 
 The telemetry-injection contract
 --------------------------------
 
 Observability follows the same declarative shape: a
 :class:`~repro.obs.spec.TelemetrySpec` on the
-:class:`~repro.core.plan.ExecutionPlan` (``plan.telemetry``; the legacy
-boolean form still parses — ``True`` means ``kind="counters"`` with a
-``DeprecationWarning``).  Unlike the scheduler/partitioner/kernel
-contracts, instrumentation is engine-owned and rides *outside* the
-primitives, so it can never change what a round computes; an app may
-add one optional hook.
+:class:`~repro.core.plan.ExecutionPlan` (``plan.telemetry``; ``False``
+is off).  Unlike the scheduler/partitioner/kernel contracts,
+instrumentation is engine-owned and rides *outside* the primitives, so
+it can never change what a round computes; an app may add one optional
+hook.
 
 * **Device counters** (any spec) are an extra pytree leaf threaded
   through every executor's carry (``EngineCarry.obs`` /
@@ -290,12 +288,6 @@ mediated by :class:`~repro.core.kvstore.VarTable`):
   via ``var_roles() -> {leaf_path: "priority"}`` and get the VarTable
   in-flight exclusion; apps using an injected scheduler need neither —
   the carry-based ``mark_scheduled`` above covers it.
-
-The v1 protocol's four ``ssp_commit_local`` / ``ssp_defer_local`` /
-``ssp_commit_shared`` / ``ssp_mark_scheduled`` hook overrides are
-deprecated: :mod:`repro.ps.ssp` still honors them (with a
-``DeprecationWarning``) when an app defines any, but the built-in apps
-rely purely on the derived behavior.
 """
 from __future__ import annotations
 

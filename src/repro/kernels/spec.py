@@ -16,9 +16,8 @@ partitioning policy ones:
   combination raises here, at spec-build time, never at trace time;
 * **JSON-round-trippable** — ``to_json``/``from_json`` are exact
   (defaults included), so specs live inside checked-in plan files
-  (``examples/plans/lasso_pallas.json``), benchmark records
-  (``BENCH_kernels.json``) and CLI flags (``launch/dryrun.py
-  --kernels``).
+  (``examples/plans/lasso_pallas.json``) and CLI flags
+  (``launch/dryrun.py --kernels``).
 
 The spec is backend policy only — it never names an app or a shape.
 Execution details (which jax platform is live, hence whether the Pallas

@@ -1,9 +1,8 @@
 """Where the entry points keep JAX's persistent compilation cache.
 
 Called once by each entry point (``chip_smoke.py``, ``launch/serve.py``,
-``launch/train.py``, ``benchmarks/run.py``) before it compiles anything;
-no library module calls it, so importing ``repro`` never changes jax
-configuration.
+``launch/train.py``) before it compiles anything; no library module
+calls it, so importing ``repro`` never changes jax configuration.
 """
 from __future__ import annotations
 

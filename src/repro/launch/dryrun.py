@@ -10,7 +10,7 @@ shape) on the production meshes, record memory/cost/collective analysis.
     PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
 
 ``--engine lasso|lda|mf`` instead lowers the multi-round STRADS executor
-(``StradsEngine.run_scanned``) on a worker mesh carved from the forced
+(``StradsEngine.scanned_fn``) on a worker mesh carved from the forced
 512-device topology — proving that R rounds × U workers compile into ONE
 XLA program (scan + psum + donated state) at production scale:
 
@@ -18,7 +18,7 @@ XLA program (scan + psum + donated state) at production scale:
         --workers 16 --rounds 16 --pipeline-depth 1
 
 ``--staleness s`` lowers the bounded-staleness SSP program
-(``StradsEngine.run_ssp`` — worker caches, lazy pushes, batched flush
+(``StradsEngine.ssp_fn`` — worker caches, lazy pushes, batched flush
 collectives) instead of the BSP scan:
 
     PYTHONPATH=src python -m repro.launch.dryrun --engine lda \

@@ -8,7 +8,7 @@
 An artifact is any JSON file carrying a :class:`~repro.obs.report
 .RunReport` — a bare ``report.to_json()`` dump, a dry-run engine record
 (``launch/dryrun.py --telemetry``/``--plan`` puts one under
-``"run_report"``), or a ``BENCH_obs.json`` entry.  The CLI prints each
+``"run_report"``), or any JSON record that embeds one.  The CLI prints each
 report's :meth:`~repro.obs.report.RunReport.summary` and, with
 ``--check``, enforces the observability contract offline:
 

@@ -13,12 +13,13 @@ flags or ``plan.scheduler`` — kind ``block_structural``).
 
 ``--scan-steps K`` rolls K train steps into a single ``lax.scan`` XLA
 program with donated state (the training-substrate twin of
-``StradsEngine.run_scanned``): one dispatch and one host sync per K
+the engine's ``"scan"`` executor): one dispatch and one host sync per K
 steps instead of per step.
 
 ``--staleness s`` (with ``--strads``) serves the block schedule from an
 SSP-style stale cache: priorities are re-read and the schedule recomputed
-only every s+1 steps (the trainer twin of ``StradsEngine.run_ssp``).
+only every s+1 steps (the trainer twin of the engine's ``"ssp"``
+executor).
 
 ``--plan plan.json`` drives the same knobs declaratively from an
 :class:`repro.core.ExecutionPlan` (rounds → steps, ``phase_unroll`` →
@@ -195,7 +196,7 @@ def main(argv=None):
         step_fn = make_train_step(cfg, tc)
 
     def chunk_fn(state, batches):
-        # K steps as one scanned XLA program (run_scanned's sibling)
+        # K steps as one scanned XLA program (the scan executor's sibling)
         def body(st, batch):
             return step_fn(st, batch)
         return jax.lax.scan(body, state, batches)

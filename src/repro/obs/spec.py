@@ -11,8 +11,8 @@ scheduler/partitioner/kernel policies already are:
   combination raises here, at spec-build time, never at trace time;
 * **JSON-round-trippable** — ``to_json``/``from_json`` are exact
   (defaults included), so specs live inside checked-in plan files
-  (``examples/plans/``), benchmark records (``BENCH_obs.json``) and CLI
-  flags (``launch/dryrun.py --telemetry``).
+  (``examples/plans/``) and CLI flags (``launch/dryrun.py
+  --telemetry``).
 
 Two kinds, by cost:
 

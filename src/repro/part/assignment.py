@@ -9,7 +9,7 @@ two runs (or two chunks of one run) under the same assignment share
 programs and a rebalance is exactly one cache miss.
 
 It round-trips two ways: ``to_json``/``from_json`` for artifacts
-(``BENCH_part.json``, dry-run records) and ``payload``/``from_payload``
+(dry-run records) and ``payload``/``from_payload``
 as a flat dict of numpy arrays for ``checkpoint/npz`` — the
 ``{"state", "carry", "assignment"}`` checkpoints
 ``StradsEngine.execute`` writes at chunk boundaries.
@@ -78,7 +78,7 @@ class Assignment:
     def spread(self, weights) -> float:
         """Relative per-worker load spread ``(max − min) / mean`` — the
         imbalance quantity ``PartitionerSpec.imbalance_threshold`` gates
-        on and ``BENCH_part.json`` reports (0 = perfectly balanced)."""
+        on (0 = perfectly balanced)."""
         loads = self.loads(weights)
         mean = float(loads.mean())
         if mean == 0.0:

@@ -13,9 +13,8 @@ the :class:`~repro.core.ExecutionPlan`, exactly like
   combination raises here, at spec-build time, never at trace time;
 * **JSON-round-trippable** — ``to_json``/``from_json`` are exact
   (defaults included), so specs live inside checked-in plan files
-  (``examples/plans/lasso_loadbal.json``), benchmark records
-  (``BENCH_part.json``) and CLI flags (``launch/dryrun.py
-  --partitioner``).
+  (``examples/plans/lasso_loadbal.json``) and CLI flags
+  (``launch/dryrun.py --partitioner``).
 
 The spec is policy only — it never names an app.  Structural dimensions
 (how many partitionable variables, how many workers, per-variable sizes)

@@ -12,8 +12,8 @@ stale.  On the STRADS primitives that becomes:
   a pending-update buffer (no collective), and only when the staleness
   gate ``clock - cache.clock <= s`` would be violated does a **flush**
   run — one batched psum for every deferred round, then the deferred
-  commits (``ssp_commit_shared``, default ``pull``) replayed in round
-  order, then a cache refresh;
+  commits (the app's ``pull``) replayed in round order, then a cache
+  refresh;
 * **worker-local** state stays exact: commit-through runs every round so
   a worker always sees its *own* writes immediately (the SSP
   read-my-writes guarantee) — only other workers' contributions arrive
@@ -32,28 +32,24 @@ injected scheduler (the v2 scheduler-injection contract) the priority
 table lives in the engine-owned scheduler carry instead: the window
 scheduler masks it via ``scheduler.mark_scheduled`` between stale
 proposals, folds it forward via ``app.sched_update`` per replayed
-commit, and returns it as ``SSPCarry.sched_carry``.  Apps that still
-define the deprecated v1 ``ssp_*`` hook overrides are honored with a
-``DeprecationWarning``.
+commit, and returns it as ``SSPCarry.sched_carry``.
 
 Rounds therefore execute in windows of ``s + 1``: the first round of a
 window reads a fresh snapshot (staleness 0), the last reads one that is
 ``s`` commits old.  Schedules for a whole window are computed up front
-from the same snapshot — the direct generalization of the engine's
-``pipeline_depth=1`` schedule prefetch (one-round-stale schedules) to
+from the same snapshot — the direct generalization of the pipelined
+executor's schedule prefetch (one-round-stale schedules) to
 ``≤ s``-round-stale schedules, with the window's ``schedule_stats``
 reductions batched into a single collective.
 
 At ``staleness=0`` every window is one round: the gate forces a flush
 after every push, the batched psum degenerates to the BSP pull
-aggregation, and the executor is **bit-identical** to
-``StradsEngine.run_scanned(pipeline_depth=0)`` — the correctness anchor
-(``tests/test_ssp.py``).  At ``s >= 1`` the program issues ~2 collectives
-per window instead of ~2 per round; the price is staleness error in the
-deferred commits, which ``benchmarks/bench_ssp.py`` measures as
-objective-vs-round for ``s ∈ {0,1,2,4}``.
+aggregation, and the executor is **bit-identical** to the ``"scan"``
+executor — the correctness anchor (``tests/test_ssp.py``).  At
+``s >= 1`` the program issues ~2 collectives per window instead of ~2
+per round; the price is staleness error in the deferred commits.
 
-Built on the same ``lax.scan`` skeleton as ``run_scanned``: one XLA
+Built on the same ``lax.scan`` skeleton as the scan executor: one XLA
 program for all R rounds, donated state, no per-round host sync.  The
 scan carries ``(state, rng, round counter, vector clocks, telemetry,
 engine-wide counters)``; the carry is exposed as :class:`SSPCarry` so a
@@ -65,7 +61,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import warnings
 from typing import Any, Callable, List, Optional
 
 import jax
@@ -138,18 +133,14 @@ def _batched_psum(trees: List[Any], axis_name: str) -> List[Any]:
 
 
 # ---------------------------------------------------------------------------
-# Commit/defer/exclusion — derived from placement (v2) or legacy hooks
+# Commit/defer/exclusion — derived from placement
 # ---------------------------------------------------------------------------
 
-_LEGACY_HOOKS = ("ssp_commit_local", "ssp_defer_local",
-                 "ssp_commit_shared", "ssp_mark_scheduled")
-
-
 class _DerivedHooks:
-    """The v2 contract: everything follows from the VarSpec placement
-    (commit-through of worker-resident ``local`` writes, deferral of the
-    rest, flush-time replay of the app's own ``pull``, in-flight
-    exclusion over ``role="priority"`` leaves)."""
+    """Everything follows from the VarSpec placement (commit-through of
+    worker-resident ``local`` writes, deferral of the rest, flush-time
+    replay of the app's own ``pull``, in-flight exclusion over
+    ``role="priority"`` leaves)."""
 
     def __init__(self, app, table: VarTable):
         self.app = app
@@ -167,44 +158,6 @@ class _DerivedHooks:
 
     def mark_scheduled(self, view, candidates, phase):
         return self.table.mark_scheduled(view, candidates)
-
-
-class _LegacyHooks:
-    """v1 per-app ``ssp_*`` hook overrides (deprecated), with the old
-    StradsAppBase defaults filled in for whichever hooks are missing."""
-
-    def __init__(self, app):
-        self.app = app
-
-    def commit_local(self, state, sched, local, data, phase):
-        fn = getattr(self.app, "ssp_commit_local", None)
-        return fn(state, sched, local, data, phase) if fn else state
-
-    def defer_local(self, local, phase):
-        fn = getattr(self.app, "ssp_defer_local", None)
-        return fn(local, phase) if fn else local
-
-    def commit_shared(self, state, sched, z, keep, data, phase):
-        fn = getattr(self.app, "ssp_commit_shared", None)
-        if fn:
-            return fn(state, sched, z, keep, data, phase)
-        return self.app.pull(state, sched, z, keep, data, phase)
-
-    def mark_scheduled(self, view, candidates, phase):
-        fn = getattr(self.app, "ssp_mark_scheduled", None)
-        return fn(view, candidates, phase) if fn else view
-
-
-def _make_hooks(app, table: VarTable):
-    legacy = [n for n in _LEGACY_HOOKS if callable(getattr(app, n, None))]
-    if legacy:
-        warnings.warn(
-            f"{type(app).__name__} defines v1 SSP hook(s) {legacy}; they "
-            f"are deprecated — the v2 protocol derives commit/defer/"
-            f"exclusion from VarSpec placement (see repro.core.primitives)",
-            DeprecationWarning, stacklevel=3)
-        return _LegacyHooks(app)
-    return _DerivedHooks(app, table)
 
 
 # ---------------------------------------------------------------------------
@@ -358,7 +311,7 @@ def _build_ssp(eng, num_steps: int, staleness: int,
             server = ParameterServer.from_state(eng.mesh, state,
                                                 eng._sspec(state),
                                                 roles=eng.app_roles())
-        hooks = _make_hooks(eng.app, VarTable(server.store))
+        hooks = _DerivedHooks(eng.app, VarTable(server.store))
         # engine-wide counters (the telemetry-injection contract):
         # observe only the schedule pytree, so the instrumented program
         # stays bit-identical in state/PRNG
@@ -489,7 +442,7 @@ def _get_ssp_fn(eng, num_steps: int, staleness: int,
 
 
 # ---------------------------------------------------------------------------
-# Public entry points
+# Entry points: the AOT program and the ssp executor's runner
 # ---------------------------------------------------------------------------
 
 def ssp_fn(eng, num_rounds: int, *, staleness: int = 0,
@@ -500,82 +453,42 @@ def ssp_fn(eng, num_rounds: int, *, staleness: int = 0,
     pass ``engine.init_sched_carry()`` for a fresh run and ``None`` — or
     ``repro.obs.init_counters(engine.phase_period)`` — for ``obs``).
     """
-    num_steps = _check_rounds(eng, num_rounds, staleness)
-    return _get_ssp_fn(eng, num_steps, staleness, collect, donate)[0]
-
-
-def _check_rounds(eng, num_rounds: int, staleness: int) -> int:
     if staleness < 0:
         raise ValueError(f"staleness must be >= 0, got {staleness}")
     L = rounds_per_step(eng, staleness)
     num_steps, tail = divmod(num_rounds, L)
     if tail or num_steps == 0:
         raise ValueError(
-            f"run_ssp needs num_rounds to be a positive multiple of "
+            f"ssp_fn needs num_rounds to be a positive multiple of "
             f"lcm(staleness+1, phase_period) = {L}; got {num_rounds}")
-    return num_steps
+    return _get_ssp_fn(eng, num_steps, staleness, collect, donate)[0]
 
 
-_UNSET = object()
-
-
-def run_ssp(eng, state, data, rng, num_rounds: int, *, staleness: int = 0,
-            collect: Optional[Callable] = None, donate: bool = True,
-            with_telemetry: bool = False, t0: int = 0,
-            clocks: Optional[jax.Array] = None,
-            sched_carry0: Any = _UNSET, obs0: Any = None,
-            return_carry: bool = False):
-    """Execute ``num_rounds`` rounds under bounded staleness ``s``.
-
-    ``staleness=0`` reproduces ``run_scanned(pipeline_depth=0)`` (and the
-    host loop) bit-for-bit — same PRNG stream, same op order.  At ``s>=1``
-    reads of server-resident state are up to ``s`` rounds stale and pushes
-    aggregate lazily (one batched collective per ``s+1``-round window).
-
-    ``collect(state)`` is evaluated after every committed round inside
-    the flush; the stacked trace has leading axis ``num_rounds``.
-
-    ``t0``/``clocks``/``sched_carry0`` resume a previous run (pass the
-    values from a saved :class:`SSPCarry`; ``t0`` must be a multiple of
-    the step length, ``sched_carry0`` is the engine-owned scheduler
-    carry — omitted, a fresh ``scheduler.init_carry()`` is used, which
-    is only correct at ``t0=0``).  ``obs0`` threads the engine-wide
-    device telemetry counters (:func:`repro.obs.counters.init_counters`,
-    or a previous :class:`SSPCarry`'s ``obs``) through the scan;
-    ``None`` runs uninstrumented.  ``return_carry=True`` appends the
-    final carry to the return value; ``with_telemetry=True`` appends an
-    :class:`~repro.ps.telemetry.SSPTelemetry`.
-    """
-    num_steps = _check_rounds(eng, num_rounds, staleness)
+def run_span(eng, state, data, rng, plan, rounds: int, t0: int, clocks,
+             sched_carry, obs, collect: Optional[Callable]):
+    """The ssp executor behind ``StradsEngine.execute``: ``rounds``
+    rounds from round ``t0`` under ``plan.staleness``, whole steps from a
+    step boundary (``execute`` checked both).  ``clocks`` resumes the
+    vector clocks of a previous :class:`SSPCarry` (``None`` starts them
+    at 0); ``sched_carry`` and ``obs`` are the scheduler carry and
+    device counters to thread through.  Returns ``(state, trace,
+    telemetry, carry)``: ``telemetry`` is the span's
+    :class:`~repro.ps.telemetry.SSPTelemetry` when the plan is
+    instrumented, else ``None``."""
+    staleness = plan.staleness
     L = rounds_per_step(eng, staleness)
-    if t0 % L:
-        raise ValueError(f"t0 must be a multiple of the step length {L} "
-                         f"(phase/window alignment); got {t0}")
-    num_workers = eng.mesh.shape[DATA_AXIS]
+    num_steps = rounds // L
     if clocks is None:
-        clocks = init_clocks(num_workers)
-    if sched_carry0 is _UNSET:
-        sched_carry0 = eng.init_sched_carry()
-        if t0 and sched_carry0 is not None:
-            warnings.warn(
-                "run_ssp(t0>0) without sched_carry0 reinitializes the "
-                "stateful scheduler's priorities; pass the "
-                "SSPCarry.sched_carry a previous run returned for a "
-                "bit-exact resume", UserWarning, stacklevel=2)
-    fn, info = _get_ssp_fn(eng, num_steps, staleness, collect, donate)
-    rng, clocks, sched_carry0, obs0 = eng.replicate(
-        (rng, jnp.asarray(clocks), sched_carry0, obs0))
+        clocks = init_clocks(eng.mesh.shape[DATA_AXIS])
+    fn, info = _get_ssp_fn(eng, num_steps, staleness, collect, plan.donate)
+    rng, clocks, sched_carry, obs = eng.replicate(
+        (rng, jnp.asarray(clocks), sched_carry, obs))
     state, carry, telem, ys = fn(state, data, rng, jnp.int32(t0), clocks,
-                                 sched_carry0, obs0)
-
-    ret = [state]
-    if collect is not None:
-        ret.append(ys)
-    if with_telemetry:
+                                 sched_carry, obs)
+    summary = None
+    if plan.telemetry:
         flushes = num_steps * (L // (staleness + 1))
-        ret.append(T.summarize(telem, info, staleness=staleness,
-                               rounds=num_rounds, flushes=flushes,
-                               clocks=carry.clocks))
-    if return_carry:
-        ret.append(carry)
-    return ret[0] if len(ret) == 1 else tuple(ret)
+        summary = T.summarize(telem, info, staleness=staleness,
+                              rounds=rounds, flushes=flushes,
+                              clocks=carry.clocks)
+    return state, ys, summary, carry

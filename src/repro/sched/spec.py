@@ -12,8 +12,8 @@ exactly like the executor choice already is:
   combination raises here, at spec-build time, never at trace time;
 * **JSON-round-trippable** — ``to_json``/``from_json`` are exact
   (defaults included), so specs live inside checked-in plan files
-  (``examples/plans/``), benchmark records (``BENCH_sched.json``) and
-  CLI flags (``launch/dryrun.py --scheduler/--rho``).
+  (``examples/plans/``) and CLI flags (``launch/dryrun.py
+  --scheduler/--rho``).
 
 The spec is policy only — it never names an app.  Structural dimensions
 (how many schedulable variables, how many workers) come from the app and

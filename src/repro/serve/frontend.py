@@ -17,7 +17,7 @@ KernelSpec)`` — the same key the engine's compiled round programs use —
 so a partition rebalance or kernel-backend swap is one cache miss, and
 a swap back is a hit.  Per-request latency (submit → response ready) and
 per-batch staleness-at-read are recorded for the p50/p99 + histogram
-reporting in ``launch/serve.py`` / ``BENCH_serve.json``.
+reporting in ``launch/serve.py``.
 """
 from __future__ import annotations
 

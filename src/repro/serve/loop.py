@@ -207,7 +207,7 @@ def serve_only(engine, state, *, spec: Optional[ServeSpec] = None,
                requests: Sequence[Any] = (), t: int = 0,
                recorder=None) -> ServeReport:
     """Serve ``requests`` (plain payloads, no due rounds) from a fixed
-    trained state — the no-training baseline arm of ``BENCH_serve``.
+    trained state — the no-training baseline for serving latency.
     ``t`` stamps the clock the state is committed through."""
     spec = _resolve_spec(spec, None)
     view = ModelView(engine, spec, recorder=recorder)

@@ -12,9 +12,8 @@ made placement policy declarative:
 * **validated at construction** — every invalid kind/parameter
   combination raises here, at spec-build time, never mid-serve;
 * **JSON-round-trippable** — ``to_json``/``from_json`` are exact
-  (defaults included), so specs live inside benchmark records
-  (``BENCH_serve.json``) and CLI flags (``launch/serve.py
-  --serve-kind``).
+  (defaults included), so specs live inside records and CLI flags
+  (``launch/serve.py --serve-kind``).
 
 The spec is policy only — it never names an app.  What a query computes
 comes from the app's ``query()`` primitive; where the served values come

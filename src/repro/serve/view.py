@@ -151,7 +151,7 @@ class ModelView:
 
     def staleness_hist(self) -> dict:
         """``{staleness: read count}`` over every read served so far —
-        the BENCH_serve histogram."""
+        the staleness-at-read histogram."""
         hist: dict = {}
         for r in self.reads:
             hist[r["staleness"]] = hist.get(r["staleness"], 0) + 1

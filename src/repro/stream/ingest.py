@@ -15,7 +15,7 @@ publishes there.  The Ingestor rides the same boundaries:
   ``ingest_specs()["valid"]`` mask says which slots hold real rows at
   bind time), then wrap around and overwrite the oldest rows.  Data
   shapes never change, so the compiled round programs are reused — not
-  recompiled (asserted in ``benchmarks/bench_stream.py``).
+  recompiled (asserted in ``tests/test_stream.py``).
 
 The cursor (``cursor``/``rows_in``/``rows_dropped``/``fill0``) is plain
 flat numpy and rides the checkpoint payload beside ``"state"`` /

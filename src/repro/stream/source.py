@@ -95,7 +95,7 @@ class LassoDriftSource:
     """Replace-kind drift for the lasso app: every ``t > 0`` boundary
     refreshes ``rows_per_ingest`` observation rows drawn from a slowly
     drifting ground-truth ``beta`` — so the objective genuinely moves
-    under ingest (benchmarked in ``bench_stream.py``)."""
+    under ingest."""
 
     num_rows: int
     num_features: int

@@ -10,8 +10,8 @@ made the read half declarative:
 * **validated at construction** — every invalid kind/parameter
   combination raises here, at spec-build time, never mid-ingest;
 * **JSON-round-trippable** — ``to_json``/``from_json`` are exact
-  (defaults included), so specs live inside benchmark records
-  (``BENCH_stream.json``) and CLI flags (``launch/serve.py --stream``).
+  (defaults included), so specs live inside records and CLI flags
+  (``launch/serve.py --stream``).
 
 The spec is policy only — it never names an app.  *What* an ingested
 delta means (which leaves, how derived state catches up) comes from the

@@ -6,6 +6,7 @@ plan path (``StradsEngine.execute`` chunked by ``plan.checkpoint_every``,
 ``ExecutionReport.carry`` round-trips) and the trainer-level
 ``launch/train.py --resume --plan`` path.
 """
+import dataclasses
 import json
 
 import numpy as np
@@ -14,7 +15,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.apps import lasso
+from repro.apps import lasso, lda, mf
 from repro.checkpoint import (latest_step, load_flat, restore_checkpoint,
                               save_checkpoint)
 from repro.core import ExecutionPlan, single_device_mesh
@@ -39,30 +40,31 @@ def _setup(rng):
 
 def test_ssp_resume_matches_uninterrupted(tmp_path, rng):
     eng, data, y = _setup(rng)
-    s = 1
+    plan = ExecutionPlan(executor="ssp", rounds=8, staleness=1)
 
     # uninterrupted: 8 rounds in one go
-    full = eng.run_ssp(eng.init_state(jax.random.key(0), y=y), data,
-                       jax.random.key(1), 8, staleness=s)
+    full = eng.execute(eng.init_state(jax.random.key(0), y=y), data,
+                       jax.random.key(1), plan).state
 
     # interrupted: 4 rounds, checkpoint the full run state, restore into
-    # a fresh template, continue 4 more
-    st, carry = eng.run_ssp(eng.init_state(jax.random.key(0), y=y), data,
-                            jax.random.key(1), 4, staleness=s,
-                            return_carry=True)
-    save_checkpoint(str(tmp_path), 4, {"state": st, "carry": carry})
+    # a fresh template, continue to round 8
+    half = eng.execute(eng.init_state(jax.random.key(0), y=y), data,
+                       jax.random.key(1),
+                       ExecutionPlan(executor="ssp", rounds=4, staleness=1))
+    save_checkpoint(str(tmp_path), 4, {"state": half.state,
+                                       "carry": half.carry})
     assert latest_step(str(tmp_path)) == 4
 
-    template = {"state": jax.tree.map(jnp.copy, st), "carry": carry}
+    template = {"state": jax.tree.map(jnp.copy, half.state),
+                "carry": half.carry}
     restored = restore_checkpoint(str(tmp_path), 4, template)
     c = restored["carry"]
     assert int(c.t) == 4 and (np.asarray(c.clocks) == 4).all()
     # the scheduler carry (Δβ priority history) is part of SSPCarry now
     # and must resume with the rest of the carry for bit-exactness
     assert c.sched_carry is not None
-    resumed = eng.run_ssp(restored["state"], data, c.rng, 4, staleness=s,
-                          t0=int(c.t), clocks=c.clocks,
-                          sched_carry0=c.sched_carry)
+    resumed = eng.execute(restored["state"], data, jax.random.key(99),
+                          plan, carry=c).state
     _bit_identical(full, resumed)
 
 
@@ -71,37 +73,40 @@ def test_scanned_sched_carry_roundtrips_through_npz(tmp_path, rng):
     now — ``{"state", "carry"}`` round-trips it through checkpoint/npz,
     and resuming from it continues the dynamic schedule bit-exactly."""
     eng, data, y = _setup(rng)
+    plan = ExecutionPlan(executor="scan", rounds=8, donate=False)
 
-    full, full_carry = eng.run_scanned(
-        eng.init_state(jax.random.key(0), y=y), data, jax.random.key(1),
-        8, return_carry=True)
+    full_rep = eng.execute(eng.init_state(jax.random.key(0), y=y), data,
+                           jax.random.key(1), plan)
+    full = full_rep.state
 
-    st, carry = eng.run_scanned(eng.init_state(jax.random.key(0), y=y),
-                                data, jax.random.key(1), 4,
-                                return_carry=True)
+    half = eng.execute(eng.init_state(jax.random.key(0), y=y), data,
+                       jax.random.key(1),
+                       ExecutionPlan(executor="scan", rounds=4))
+    carry = half.carry
     assert carry.sched_carry is not None        # the Δβ priority history
-    save_checkpoint(str(tmp_path), 4, {"state": st, "carry": carry})
-    template = {"state": jax.tree.map(jnp.copy, st), "carry": carry}
+    save_checkpoint(str(tmp_path), 4, {"state": half.state, "carry": carry})
+    template = {"state": jax.tree.map(jnp.copy, half.state), "carry": carry}
     back = restore_checkpoint(str(tmp_path), 4, template)
     c = back["carry"]
     assert (np.asarray(c.sched_carry)
             == np.asarray(carry.sched_carry)).all()
-    resumed, res_carry = eng.run_scanned(back["state"], data, c.rng, 4,
-                                         t0=int(c.t), donate=False,
-                                         sched_carry0=c.sched_carry,
-                                         return_carry=True)
-    _bit_identical(full, resumed)
+    res = eng.execute(back["state"], data, jax.random.key(99), plan,
+                      carry=c)
+    _bit_identical(full, res.state)
     # the final carries of full vs chunked runs agree exactly
-    assert (np.asarray(full_carry.sched_carry)
-            == np.asarray(res_carry.sched_carry)).all()
-    # the carry is load-bearing: resuming with a FRESH carry (uniform
-    # priorities) must diverge from the uninterrupted dynamic schedule —
-    # and omitting it at t0>0 warns about exactly that
-    with pytest.warns(UserWarning, match="without sched_carry0"):
-        fresh = eng.run_scanned(back["state"], data, c.rng, 4,
-                                t0=int(c.t), donate=False)
-    assert not (np.asarray(fresh["beta"])
+    assert (np.asarray(full_rep.carry.sched_carry)
+            == np.asarray(res.carry.sched_carry)).all()
+    # the carry is load-bearing: resuming with a FRESH scheduler carry
+    # (uniform priorities) must diverge from the uninterrupted dynamic
+    # schedule — and a carry without one is refused
+    fresh = dataclasses.replace(c, sched_carry=eng.init_sched_carry())
+    diverged = eng.execute(back["state"], data, jax.random.key(99), plan,
+                           carry=fresh).state
+    assert not (np.asarray(diverged["beta"])
                 == np.asarray(full["beta"])).all()
+    with pytest.raises(ValueError, match="scheduler carry"):
+        eng.execute(back["state"], data, jax.random.key(99), plan,
+                    carry=dataclasses.replace(c, sched_carry=None))
 
 
 def test_execute_plan_checkpoint_chunks_match_uninterrupted(tmp_path,
@@ -327,10 +332,67 @@ def test_execute_rejects_misaligned_checkpoint_cadence(tmp_path, rng):
 
 
 def test_ssp_resume_rejects_misaligned_t0(rng):
+    """An SSPCarry whose round is off the s=1 window (length 2) cannot
+    resume: its phases and windows would not line up with the steps."""
     eng, data, y = _setup(rng)
-    st = eng.init_state(jax.random.key(0), y=y)
+    rep = eng.execute(eng.init_state(jax.random.key(0), y=y), data,
+                      jax.random.key(1),
+                      ExecutionPlan(executor="ssp", rounds=4, staleness=1))
+    carry = dataclasses.replace(rep.carry, t=jnp.int32(3))
     with pytest.raises(ValueError, match="t0"):
-        eng.run_ssp(st, data, jax.random.key(1), 4, staleness=1, t0=3)
+        eng.execute(rep.state, data, None,
+                    ExecutionPlan(executor="ssp", rounds=8, staleness=1),
+                    carry=carry)
+
+
+def _lda_setup(rng):
+    cfg = lda.LDAConfig(vocab=30, num_topics=4, num_workers=1,
+                        tokens_per_worker=200, docs_per_worker=5)
+    words, docs, z0 = lda.synthetic_corpus(rng, cfg, true_topics=4)
+    eng = lda.make_engine(cfg, single_device_mesh())
+    data = eng.shard_data({"words": jnp.asarray(words),
+                           "docs": jnp.asarray(docs)})
+    return eng, data, lambda: eng.init_state(jax.random.key(0),
+                                             words=words, docs=docs, z0=z0)
+
+
+def _mf_setup(rng):
+    A, mask = mf.synthetic_ratings(rng, 40, 30, true_rank=4, density=0.5)
+    cfg = mf.MFConfig(num_rows=40, num_cols=30, rank=4, lam=0.05)
+    eng = mf.make_engine(cfg, single_device_mesh())
+    data = eng.shard_data(eng.app.layout(A, mask))
+    return eng, data, lambda: eng.init_state(jax.random.key(0), data=data)
+
+
+@pytest.mark.parametrize("app,executor,staleness", [
+    ("lda", "loop", 0), ("lda", "scan", 0), ("lda", "pipelined", 0),
+    ("lda", "ssp", 1),
+    ("mf", "loop", 0), ("mf", "pipelined", 0), ("mf", "ssp", 1),
+])
+def test_chunked_resume_matches_unchunked(tmp_path, rng, app, executor,
+                                          staleness):
+    """A run chunked by ``checkpoint_every``, and the same run resumed
+    from its mid-run checkpoint with ``carry=``, each equal the
+    unchunked run bit for bit — the resume every benchmark chunk makes.
+    (MF under scan is ``test_mf.py``'s sparse-layout resume case.)"""
+    eng, data, init = {"lda": _lda_setup, "mf": _mf_setup}[app](rng)
+    kw = dict(executor=executor, rounds=8, staleness=staleness)
+    full = eng.execute(init(), data, jax.random.key(1),
+                       ExecutionPlan(**kw)).state
+
+    plan = ExecutionPlan(checkpoint_every=4, **kw)
+    rep = eng.execute(init(), data, jax.random.key(1), plan,
+                      ckpt_dir=str(tmp_path))
+    _bit_identical(full, rep.state)
+
+    template = {"state": jax.tree.map(jnp.copy, rep.state),
+                "carry": rep.carry}
+    restored = restore_checkpoint(str(tmp_path), 4, template)
+    assert int(restored["carry"].t) == 4
+    resumed = eng.execute(restored["state"], data, jax.random.key(99),
+                          plan, carry=restored["carry"],
+                          ckpt_dir=str(tmp_path / "resumed"))
+    _bit_identical(full, resumed.state)
 
 
 @pytest.mark.slow

@@ -1,10 +1,11 @@
-"""The pipelined multi-round executor (engine.run_scanned).
+"""The scanned multi-round executors (``execute`` with executor "scan"
+and "pipelined").
 
 Contract under test (PR 1 acceptance):
-  * ``run_scanned(..., pipeline_depth=0)`` is bit-identical to the host
-    loop ``run`` on all three paper apps — same PRNG stream, same op
-    order, one XLA program instead of R dispatches.
-  * ``pipeline_depth=1`` (schedule prefetch, one-round-stale schedules —
+  * ``"scan"`` is bit-identical to the host loop ``"loop"`` on all three
+    paper apps — same PRNG stream, same op order, one XLA program
+    instead of R dispatches.
+  * ``"pipelined"`` (schedule prefetch, one-round-stale schedules —
     the paper's §pipelining) still monotonically decreases the Lasso
     objective on a correlated design.
   * phase-period handling: apps whose round structure cycles (MF's H/W
@@ -162,8 +163,10 @@ def test_run_scanned_without_collect_returns_state_only(mesh, rng):
     eng = lasso.make_engine(cfg, mesh)
     data = eng.shard_data({"X": jnp.asarray(X), "y": jnp.asarray(y)})
     state = eng.app.init_state(jax.random.key(0), y=y)
-    out = eng.run_scanned(state, data, jax.random.key(0), 4)
-    assert isinstance(out, dict) and set(out) == {"beta", "r"}
+    rep = eng.execute(state, data, jax.random.key(0),
+                      ExecutionPlan(executor="scan", rounds=4))
+    assert rep.trace is None
+    assert isinstance(rep.state, dict) and set(rep.state) == {"beta", "r"}
 
 
 def test_run_scanned_collect_trace_has_one_entry_per_round(mesh, rng):
@@ -173,10 +176,11 @@ def test_run_scanned_collect_trace_has_one_entry_per_round(mesh, rng):
     eng = lasso.make_engine(cfg, mesh)
     data = eng.shard_data({"X": jnp.asarray(X), "y": jnp.asarray(y)})
     state = eng.app.init_state(jax.random.key(0), y=y)
-    state, ys = eng.run_scanned(state, data, jax.random.key(0), 6,
-                                collect=eng.app.objective_collect(),
-                                donate=False)
-    assert np.asarray(ys).shape == (6,)
+    rep = eng.execute(state, data, jax.random.key(0),
+                      ExecutionPlan(executor="scan", rounds=6,
+                                    donate=False),
+                      collect=eng.app.objective_collect())
+    assert np.asarray(rep.trace).shape == (6,)
 
 
 def test_scanned_fn_is_aot_lowerable(mesh, rng):

@@ -16,9 +16,8 @@ Contract under test (ISSUE 7 acceptance):
   * counters are bit-exact through ``checkpoint_every`` chunking and
     through a checkpoint/restore resume (``EngineCarry.obs`` rides the
     npz payload like every other carry leaf).
-  * the plan shim: ``telemetry=True`` still parses (DeprecationWarning →
-    ``TelemetrySpec(kind="counters")``), non-SSP executors no longer
-    reject it, and plans round-trip through JSON with specs intact.
+  * the plan field: ``False`` is off, a spec turns telemetry on for any
+    executor, and plans round-trip through JSON with specs intact.
 """
 import json
 
@@ -326,15 +325,8 @@ def test_ssp_counters_bit_exact_through_chunking(tmp_path, mesh, rng):
 
 
 # ---------------------------------------------------------------------------
-# the plan surface: spec field, bool shim, JSON round-trip
+# the plan surface: spec field, JSON round-trip
 # ---------------------------------------------------------------------------
-
-def test_plan_bool_true_shims_to_counters_spec_with_warning():
-    with pytest.warns(DeprecationWarning, match="TelemetrySpec"):
-        plan = ExecutionPlan(executor="ssp", rounds=4, staleness=1,
-                             telemetry=True)
-    assert plan.telemetry == TelemetrySpec(kind="counters")
-
 
 def test_plan_bool_false_stays_falsy():
     plan = ExecutionPlan(executor="scan", rounds=4, telemetry=False)
@@ -354,18 +346,18 @@ def test_plan_json_roundtrips_spec():
     back = ExecutionPlan.from_json(json.loads(json.dumps(plan.to_json())))
     assert back == plan
     assert back.telemetry == TelemetrySpec(kind="trace", profiler=True)
-    # and the legacy serialized-bool shape still parses
+    # and a plan with telemetry off serializes it as false
     off = ExecutionPlan.from_json(
         ExecutionPlan(executor="scan", rounds=4).to_json())
     assert off.telemetry is False
 
 
 def test_non_ssp_executor_accepts_telemetry(mesh, rng):
-    """PR-2 behavior (`telemetry=True` + scan raises) is gone: every
-    executor takes a spec now."""
+    """Telemetry is not an ssp-only field: every executor takes a
+    spec."""
     eng, data, y = _lasso_engine(rng, mesh)
-    with pytest.warns(DeprecationWarning):
-        plan = ExecutionPlan(executor="scan", rounds=4, telemetry=True)
+    plan = ExecutionPlan(executor="scan", rounds=4,
+                         telemetry=TelemetrySpec(kind="counters"))
     rep = eng.execute(eng.init_state(jax.random.key(0), y=y), data,
                       jax.random.key(1), plan)
     assert rep.telemetry.counters["rounds"] == 4

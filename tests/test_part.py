@@ -41,7 +41,7 @@ def test_spec_valid_kinds_and_json_roundtrip():
         d = spec.to_json()
         assert PartitionerSpec.from_json(d) == spec
         # every field present, defaults included (exact dumps — plan
-        # files and BENCH_part.json rely on it)
+        # files rely on it)
         assert set(d) == {"kind", "rebalance_every", "ema",
                           "imbalance_threshold"}
 

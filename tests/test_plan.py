@@ -6,15 +6,12 @@ Contract under test:
     executor-name message living in exactly one place;
   * ``to_json → from_json`` round-trips exactly, defaults included, and
     the checked-in ``examples/plans/*.json`` files parse;
-  * ``StradsEngine.execute(plan)`` drives all four executors and is
-    bit-identical to the legacy entry points (``run`` / ``run_scanned`` /
-    ``run_ssp``) on Lasso — the per-app equivalence lives in
+  * ``StradsEngine.execute(plan)`` drives all four executors with one
+    report contract, and the scan executor is bit-identical to the loop
+    and ssp at s=0 to scan on Lasso — the per-app equivalence lives in
     tests/test_engine_scan.py and tests/test_ssp.py;
-  * the deprecated ``fit(executor=..., staleness=...)`` shim warns and
-    produces bit-identical results to ``fit(plan=...)``;
   * the derived v2 SSP behavior replaced the per-app ``ssp_*`` hook
-    overrides (they are gone from the apps), while legacy hooks on a
-    user app still run behind a DeprecationWarning.
+    overrides (they are gone from the apps).
 """
 import glob
 import json
@@ -26,8 +23,8 @@ import numpy as np
 import pytest
 
 from repro.apps import lasso, lda, mf
-from repro.core import (ExecutionPlan, ExecutionReport, StradsEngine,
-                        single_device_mesh)
+from repro.core import ExecutionPlan, ExecutionReport, single_device_mesh
+from repro.obs import TelemetrySpec
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -73,13 +70,12 @@ def test_plan_rejects_unknown_executor_with_canonical_message():
 
 @pytest.mark.parametrize("kw", [
     dict(executor="scan", staleness=1),         # staleness needs ssp
-    dict(executor="scan", pipeline_depth=1),    # depth>0 needs pipelined
-    dict(executor="pipelined", pipeline_depth=0),
     dict(executor="scan", rounds=0),
     dict(executor="scan", rounds=1, staleness=-1),
     dict(executor="loop", rounds=4, phase_unroll=2),
     dict(executor="ssp", rounds=4, phase_unroll=2),
     dict(executor="scan", rounds=4, telemetry="counters"),  # not a spec
+    dict(executor="scan", rounds=4, telemetry=True),        # not a spec
     dict(executor="scan", rounds=4, workers=0),
     dict(executor="scan", rounds=4, collect_every=-1),
 ])
@@ -91,8 +87,7 @@ def test_invalid_combinations_raise_at_construction(kw):
 def test_plan_depth_derivation():
     assert ExecutionPlan(executor="scan", rounds=2).depth == 0
     assert ExecutionPlan(executor="pipelined", rounds=2).depth == 1
-    assert ExecutionPlan(executor="pipelined", rounds=2,
-                         pipeline_depth=1).depth == 1
+    assert ExecutionPlan(executor="ssp", rounds=2).depth == 0
 
 
 # ---------------------------------------------------------------------------
@@ -106,7 +101,8 @@ def test_plan_json_roundtrip_exact_including_defaults():
         ExecutionPlan(executor="pipelined", rounds=8, phase_unroll=2,
                       donate=False),
         ExecutionPlan(executor="ssp", rounds=12, staleness=2,
-                      telemetry=True, checkpoint_every=6, workers=4),
+                      telemetry=TelemetrySpec(kind="counters"),
+                      checkpoint_every=6, workers=4),
     ]
     for p in plans:
         d = p.to_json()
@@ -141,10 +137,12 @@ def test_checked_in_example_plans_parse():
 
 
 # ---------------------------------------------------------------------------
-# execute(plan) == the legacy entry points, all four executors
+# execute(plan): one report contract, all four executors
 # ---------------------------------------------------------------------------
 
 def test_execute_matches_legacy_entry_points_all_executors(mesh, rng):
+    """Every executor fills the same report; scan is bit-identical to
+    the loop, and ssp at s=0 to scan."""
     cfg, X, y = _lasso_setup(rng)
     eng = lasso.make_engine(cfg, mesh)
     data = eng.shard_data({"X": jnp.asarray(X), "y": jnp.asarray(y)})
@@ -152,24 +150,23 @@ def test_execute_matches_legacy_entry_points_all_executors(mesh, rng):
     def init():
         return eng.init_state(jax.random.key(0), y=y)
 
-    legacy = {
-        "loop": lambda: eng.run(init(), data, jax.random.key(1), 8),
-        "scan": lambda: eng.run_scanned(init(), data, jax.random.key(1),
-                                        8, pipeline_depth=0),
-        "pipelined": lambda: eng.run_scanned(init(), data,
-                                             jax.random.key(1), 8,
-                                             pipeline_depth=1),
-        "ssp": lambda: eng.run_ssp(init(), data, jax.random.key(1), 8,
-                                   staleness=1),
+    plans = {
+        "loop": ExecutionPlan(executor="loop", rounds=8),
+        "scan": ExecutionPlan(executor="scan", rounds=8),
+        "pipelined": ExecutionPlan(executor="pipelined", rounds=8),
+        "ssp": ExecutionPlan(executor="ssp", rounds=8, staleness=1),
+        "ssp0": ExecutionPlan(executor="ssp", rounds=8),
     }
-    for name, run in legacy.items():
-        plan = ExecutionPlan(executor=name, rounds=8,
-                             staleness=1 if name == "ssp" else 0)
+    states = {}
+    for name, plan in plans.items():
         rep = eng.execute(init(), data, jax.random.key(1), plan)
         assert isinstance(rep, ExecutionReport)
         assert rep.plan is plan and rep.carry is not None
         assert int(rep.carry.t) == 8
-        _bit_identical(run(), rep.state)
+        assert rep.trace is None and rep.telemetry is None
+        states[name] = rep.state
+    _bit_identical(states["loop"], states["scan"])
+    _bit_identical(states["scan"], states["ssp0"])
 
 
 def test_execute_validates_workers_and_callback(mesh, rng):
@@ -201,19 +198,8 @@ def test_execute_phase_unroll_is_bit_identical(mesh, rng):
 
 
 # ---------------------------------------------------------------------------
-# deprecation shims
+# the fit adapters
 # ---------------------------------------------------------------------------
-
-def test_fit_legacy_kwargs_warn_and_match_plan(mesh, rng):
-    cfg, X, y = _lasso_setup(rng)
-    with pytest.warns(DeprecationWarning, match="deprecated"):
-        s_legacy, _ = lasso.fit(cfg, X, y, mesh, num_rounds=9,
-                                executor="ssp", staleness=2)
-    s_plan, _ = lasso.fit(cfg, X, y, mesh,
-                          plan=ExecutionPlan(executor="ssp", rounds=9,
-                                             staleness=2))
-    _bit_identical(s_legacy, s_plan)
-
 
 def test_fit_default_path_does_not_warn(mesh, rng):
     import warnings as W
@@ -226,8 +212,6 @@ def test_fit_default_path_does_not_warn(mesh, rng):
 def test_fit_rejects_plan_plus_legacy_kwargs(mesh, rng):
     cfg, X, y = _lasso_setup(rng)
     plan = ExecutionPlan(executor="scan", rounds=4)
-    with pytest.raises(ValueError, match="not both"):
-        lasso.fit(cfg, X, y, mesh, executor="scan", plan=plan)
     with pytest.raises(ValueError, match="contradicts"):
         lasso.fit(cfg, X, y, mesh, num_rounds=5, plan=plan)
 
@@ -239,26 +223,17 @@ def test_fit_rejects_plan_fields_it_cannot_honor(mesh, rng):
     with pytest.raises(ValueError, match="telemetry"):
         lasso.fit(cfg, X, y, mesh,
                   plan=ExecutionPlan(executor="ssp", rounds=4,
-                                     staleness=1, telemetry=True))
+                                     staleness=1,
+                                     telemetry=TelemetrySpec(
+                                         kind="counters")))
     with pytest.raises(ValueError, match="checkpoint"):
         lasso.fit(cfg, X, y, mesh,
                   plan=ExecutionPlan(executor="scan", rounds=4,
                                      checkpoint_every=2))
 
 
-def test_run_zero_rounds_is_a_noop(mesh, rng):
-    """run_scanned's num_rounds>=1 error directs callers to the host
-    loop for zero-round calls — keep that escape hatch working."""
-    cfg, X, y = _lasso_setup(rng)
-    eng = lasso.make_engine(cfg, mesh)
-    data = eng.shard_data({"X": jnp.asarray(X), "y": jnp.asarray(y)})
-    state = eng.init_state(jax.random.key(0), y=y)
-    out = eng.run(state, data, jax.random.key(1), 0)
-    _bit_identical(out, state)
-
-
 # ---------------------------------------------------------------------------
-# v2 protocol: hooks are gone from the apps, legacy hooks still honored
+# v2 protocol: no SSP hooks in the apps
 # ---------------------------------------------------------------------------
 
 def test_apps_define_no_v1_ssp_hooks():
@@ -281,35 +256,3 @@ def test_lasso_default_scheduler_specs_follow_config(rng):
     assert lasso.StradsLasso(rr).default_scheduler_spec() == \
         SchedulerSpec(kind="random", block_size=8)
     assert lasso.StradsLasso(cfg).var_roles() == {}
-
-
-def test_legacy_ssp_hooks_still_run_with_deprecation_warning(mesh, rng):
-    """A user app carrying v1 hook overrides keeps working (the shim in
-    repro.ps.ssp), warns, and — when the hooks replicate the old
-    defaults — matches the derived path bit-for-bit.  Uses the "rr"
-    scheduler so neither path applies in-flight exclusion (the strads
-    priority masking has no legacy counterpart in this minimal app)."""
-    X, y, _ = lasso.synthetic_correlated(rng, n=40, J=20, k_true=3)
-    cfg = lasso.LassoConfig(num_features=20, lam=0.02, block_size=4,
-                            scheduler="rr")
-
-    class LegacyLasso(lasso.StradsLasso):
-        def ssp_commit_shared(self, state, sched, z, local, data, phase):
-            return self.pull(state, sched, z, local, data, phase)
-
-    eng_legacy = StradsEngine(LegacyLasso(cfg), mesh,
-                              data_specs=LegacyLasso(cfg).data_specs(),
-                              state_specs=LegacyLasso(cfg).state_specs())
-    data = eng_legacy.shard_data({"X": jnp.asarray(X),
-                                  "y": jnp.asarray(y)})
-    st0 = eng_legacy.init_state(jax.random.key(0), y=y)
-    with pytest.warns(DeprecationWarning, match="v1 SSP hook"):
-        s_legacy = eng_legacy.run_ssp(st0, data, jax.random.key(1), 8,
-                                      staleness=1)
-
-    eng = lasso.make_engine(cfg, mesh)
-    s_derived = eng.run_ssp(eng.init_state(jax.random.key(0), y=y),
-                            eng.shard_data({"X": jnp.asarray(X),
-                                            "y": jnp.asarray(y)}),
-                            jax.random.key(1), 8, staleness=1)
-    _bit_identical(s_legacy, s_derived)

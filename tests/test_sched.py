@@ -412,15 +412,16 @@ def test_ssp_carry_returned_and_resumable(mesh, rng):
                             num_candidates=8, rho=0.3)
     eng = lasso.make_engine(cfg, mesh)
     data = eng.shard_data({"X": jnp.asarray(X), "y": jnp.asarray(y)})
-    full = eng.run_ssp(eng.init_state(jax.random.key(0), y=y), data,
-                       jax.random.key(1), 8, staleness=1)
-    st, carry = eng.run_ssp(eng.init_state(jax.random.key(0), y=y), data,
-                            jax.random.key(1), 4, staleness=1,
-                            return_carry=True)
+    plan = ExecutionPlan(executor="ssp", rounds=8, staleness=1)
+    full = eng.execute(eng.init_state(jax.random.key(0), y=y), data,
+                       jax.random.key(1), plan).state
+    half = eng.execute(eng.init_state(jax.random.key(0), y=y), data,
+                       jax.random.key(1),
+                       ExecutionPlan(executor="ssp", rounds=4,
+                                     staleness=1))
+    carry = half.carry
     assert carry.sched_carry is not None
-    resumed = eng.run_ssp(st, data, carry.rng, 4, staleness=1,
-                          t0=int(carry.t), clocks=carry.clocks,
-                          sched_carry0=carry.sched_carry)
+    resumed = eng.execute(half.state, data, None, plan, carry=carry).state
     _bit_identical(full, resumed)
 
 

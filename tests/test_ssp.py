@@ -1,9 +1,9 @@
 """The bounded-staleness parameter-server subsystem (repro/ps).
 
 Contract under test (ISSUE 2 acceptance):
-  * ``run_ssp(staleness=0)`` is bit-identical to
-    ``run_scanned(pipeline_depth=0)`` on all three paper apps — the
-    correctness anchor for the whole subsystem.
+  * the ``"ssp"`` executor at ``staleness=0`` is bit-identical to
+    ``"scan"`` on all three paper apps — the correctness anchor for the
+    whole subsystem.
   * the staleness invariant: no read is ever served more than ``s``
     clocks stale, asserted over the *device-observed* telemetry for
     random schedules (hypothesis property; deterministic stub fallback).

@@ -33,7 +33,7 @@ from repro.apps import lasso, lda, mf
 from repro.core import ExecutionPlan, StradsAppBase, single_device_mesh
 from repro.data.pipeline import SyntheticLMConfig, make_batch
 from repro.obs import TelemetrySpec
-from repro.serve import serve_while_training
+from repro.serve import ServeSpec, serve_while_training
 from repro.stream import (EmptySource, Ingestor, LassoDriftSource,
                           LDADriftSource, MFDriftSource, ScheduledSource,
                           StreamSpec, SyntheticLMSource, replay_data)
@@ -308,6 +308,28 @@ def test_extend_streamed_execute_end_to_end(mesh):
     assert int(rep.stream["rows_dropped"]) == 0
 
 
+def test_extend_ingest_reuses_compiled_programs(mesh):
+    """Fresh extend deltas never change a data shape: once one streamed
+    run has compiled its programs, a second with other deltas compiles
+    none."""
+    eng, data, init, _ = _mf_setup(mesh)
+    spec = StreamSpec(kind="extend", ingest_every=2)
+    src = lambda seed: MFDriftSource(num_rows=12, num_cols=10,
+                                     rows_per_ingest=3, seed=seed)
+
+    def compiled():
+        return sum(f._cache_size() for f in eng._scan_cache.values()
+                   if hasattr(f, "_cache_size"))
+
+    eng.execute(init(), data, jax.random.key(1), _plan("scan", 8),
+                stream=spec, source=src(5))
+    programs, compiles = len(eng._scan_cache), compiled()
+    rep = eng.execute(init(), data, jax.random.key(1), _plan("scan", 8),
+                      stream=spec, source=src(6))
+    assert (len(eng._scan_cache), compiled()) == (programs, compiles)
+    assert int(rep.stream["rows_in"]) == 3 * 3
+
+
 def test_lda_ingest_keeps_collapsed_counts_exact(mesh):
     """After streamed ingest, the collapsed counts D/B/s must equal the
     counts materialized from scratch off (words, docs, z) — the exact
@@ -422,6 +444,22 @@ def test_serve_while_training_streamed_matches_engine(mesh):
     assert srep.ingest is not None
     assert int(srep.ingest["rows_in"]) == int(ref.stream["rows_in"])
     assert len(srep.responses) == 3
+
+
+def test_serve_while_training_streamed_holds_the_staleness_bound(mesh):
+    """Reads served while drift ingest lands at the same boundaries stay
+    within the ServeSpec bound, and some are stale."""
+    eng, data, init, (X, y) = _lasso_setup(mesh)
+    spec = ServeSpec(kind="stale", max_staleness=3, max_batch=8)
+    srep = serve_while_training(
+        eng, init(), data, jax.random.key(1), _plan("ssp", 8), spec=spec,
+        requests=[(t, {"x": jnp.asarray(X[t])}) for t in range(8)],
+        stream=StreamSpec(kind="replace", ingest_every=2),
+        source=LassoDriftSource(num_rows=48, num_features=24,
+                                rows_per_ingest=4, seed=9))
+    assert len(srep.responses) == 8
+    assert 0 < srep.max_staleness_read() <= spec.max_staleness
+    assert int(srep.ingest["rows_in"]) > 0
 
 
 def test_serve_while_training_rejects_misaligned_ingest(mesh):
