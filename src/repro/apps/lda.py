@@ -36,6 +36,7 @@ regardless of cluster size.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +46,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import StradsAppBase, StradsEngine
 from repro.core.compat import shard_map
-from repro.kernels import split_chain
+from repro.kernels import gibbs_scan, split_chain
 from repro.part import PartitionerSpec
 from repro.sched import SchedulerSpec
 
@@ -77,7 +78,31 @@ def _gibbs_scan(cfg: LDAConfig, B, D, s, words, docs, z, active_mask,
 
     Exact within the worker (counts updated after every sample); the only
     stale quantity is s̃, which starts at the synced s.  ``B`` holds the
-    word rows ``[block_start, block_start + B.shape[0])``.
+    word rows ``[block_start, block_start + B.shape[0])``.  Each token
+    draws with its subkey of the sampler's split chain.
+
+    On a TPU the token loop is one Pallas kernel
+    (:mod:`repro.kernels.gibbs_scan`); elsewhere it is :func:`_gibbs_loop`,
+    which gives the same bits.  Returns (B, D, s̃, z)."""
+    vloc = jnp.clip(words - block_start, 0, B.shape[0] - 1)
+    keys = split_chain.subkeys(rng, words.shape[0])
+    impl = jax.random.key_impl(rng)
+
+    def kernel(B, D, s, vloc, docs, z, active, keys):
+        B, D, st, drawn, _ = gibbs_scan.gibbs_scan_kernel(
+            B, D, s, vloc, docs, z, active,
+            jax.random.wrap_key_data(keys, impl=impl), alpha=cfg.alpha,
+            gamma=cfg.gamma, vgamma=cfg.padded_vocab * cfg.gamma)
+        return B, D, st, jnp.where(active, drawn.astype(z.dtype), z)
+
+    return jax.lax.platform_dependent(
+        B, D, s, vloc, docs, z, active_mask, keys, tpu=kernel,
+        default=functools.partial(_gibbs_loop, cfg, impl=impl))
+
+
+def _gibbs_loop(cfg: LDAConfig, B, D, s, vloc, docs, z, active_mask, keys,
+                *, impl):
+    """The token loop as XLA instructions.
 
     A token step reads its word's row of B and its document's row of D
     once, stacks them with s̃, takes the token's topic out of all three,
@@ -85,16 +110,14 @@ def _gibbs_scan(cfg: LDAConfig, B, D, s, words, docs, z, active_mask,
     not depend on the counts is computed for every token before the loop
     and packed so that one slice fetches a token: its local word row, its
     document, its topic, an offset that turns off an unscheduled token
-    and its subkey of the sampler's split chain.  The draw is written
-    back into the token's topic field."""
+    and its subkey.  The draw is written back into the token's topic
+    field."""
     K = cfg.num_topics
     # an unscheduled token takes topic K out and adds K back: no topic
     off = jnp.where(active_mask, 0, K)
-    vloc = jnp.clip(words - block_start, 0, B.shape[0] - 1)
     toks = jnp.concatenate(
         [jnp.stack([vloc, docs, z + off, off], axis=1).astype(jnp.uint32),
-         split_chain.subkeys(rng, words.shape[0])], axis=1)
-    impl = jax.random.key_impl(rng)
+         keys], axis=1)
     topics = jax.lax.iota(jnp.uint32, K)
     zero = jnp.uint32(0)
 
@@ -134,9 +157,9 @@ class StradsLDA(StradsAppBase):
     """Word-rotation model-parallel collapsed Gibbs on STRADS primitives."""
 
     supported_scheduler_kinds = ("rotation",)
-    # Gibbs sampling is gather/scan-bound, not matmul-bound: no Pallas
-    # hot-spot a plan could swap exists (the split chain's kernel goes by
-    # platform), so a plan asking for one is rejected at injection.
+    # the Gibbs token loop's Pallas kernel (and the split chain's) goes
+    # by platform, not by plan: a plan asking for a kernel kind has
+    # nothing to swap and is rejected at injection.
     supported_kernel_kinds = ("reference",)
 
     def __init__(self, cfg: LDAConfig):
@@ -214,18 +237,22 @@ class StradsLDA(StradsAppBase):
         return partial, local
 
     def obs_counts(self, data, sched, phase):
-        """(visited, updated) of one round, for the device counters: the
-        valid tokens every worker's Gibbs scan steps over, and those it
-        samples (its word in the worker's current block).  Reads only the
+        """(visited, updated, forwarded) of one round, for the device
+        counters: the valid tokens every worker's Gibbs scan steps over,
+        those it samples (its word in the worker's current block), and the
+        B and D row groups the Gibbs kernel takes from its ring instead of
+        HBM (:func:`repro.kernels.gibbs_scan.forwarded`).  Reads only the
         data and the rotation."""
         cfg = self.cfg
         words = data["words"].reshape(cfg.num_workers, -1)
+        docs = data["docs"].reshape(cfg.num_workers, -1)
         block = self.scheduler.block_for_worker(
             jnp.arange(cfg.num_workers), phase)
         valid = words >= 0
         active = valid & (words // cfg.block_vocab == block[:, None])
         return (jnp.sum(valid, dtype=jnp.int32),
-                jnp.sum(active, dtype=jnp.int32))
+                jnp.sum(active, dtype=jnp.int32),
+                gibbs_scan.forwarded(words % cfg.block_vocab, docs, active))
 
     def pull(self, state, sched, z, local, data, phase):
         cfg = self.cfg

@@ -14,6 +14,9 @@ entry_sweep.py — MF's indexed reads and segment sums over sparse entries
              platform, exact in float32)
 split_chain.py — a key's split chain drawn on the TPU's scalar core (no
              spec: chosen by platform, the same bits as ``lax.scan``)
+gibbs_scan.py — LDA's collapsed Gibbs token loop as one kernel, count
+             rows fetched by DMA ahead of each draw (no spec: chosen by
+             platform, the same draws as the XLA loop)
 """
 from . import ops, ref  # noqa: F401
 from .backend import (KERNEL_BACKENDS, PallasKernels,  # noqa: F401

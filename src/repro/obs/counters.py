@@ -27,6 +27,11 @@ Counters
                valid tokens scanned, tokens sampled).  Each is a
                two-word count (``[high, low]``, low < 2**30) so it cannot
                wrap on a long run; 0 for apps without the hook.
+``forwarded``  the hook's optional third count: reads a push serves from
+               on-chip memory instead of HBM (LDA: the B and D row
+               groups the Gibbs kernel's ring hands on, computed from the
+               data and the ring depth); a two-word count, 0 where the
+               hook gives two counts.
 
 The SSP staleness histogram (``staleness_init``/``observe_read``) lives
 here too — it is the same pattern (an int32 pytree in the scan carry,
@@ -55,7 +60,8 @@ def init_counters(phase_period: int) -> Dict[str, jnp.ndarray]:
             "accepted": jnp.int32(0),
             "killed": jnp.int32(0),
             "visited": jnp.zeros((2,), jnp.int32),
-            "updated": jnp.zeros((2,), jnp.int32)}
+            "updated": jnp.zeros((2,), jnp.int32),
+            "forwarded": jnp.zeros((2,), jnp.int32)}
 
 
 def _add_wide(count, x):
@@ -99,6 +105,8 @@ def observe_round(counters: Dict[str, jnp.ndarray], sched: Any,
     if counts is not None:
         c["visited"] = _add_wide(c["visited"], counts[0])
         c["updated"] = _add_wide(c["updated"], counts[1])
+        if len(counts) > 2:
+            c["forwarded"] = _add_wide(c["forwarded"], counts[2])
     return c
 
 
@@ -119,7 +127,8 @@ def summarize_counters(counters: Optional[Dict[str, Any]]) -> dict:
             "accepted": int(counters["accepted"]),
             "killed": int(counters["killed"]),
             "visited": _wide(counters["visited"]),
-            "updated": _wide(counters["updated"])}
+            "updated": _wide(counters["updated"]),
+            "forwarded": _wide(counters["forwarded"])}
 
 
 # ---------------------------------------------------------------------------

@@ -4,12 +4,14 @@ the declarative KernelSpec surface (validation, JSON round-trip, the
 build_kernels registry) and the plan-level contract: ``kernels=None``
 resolves to the reference backend bit-identically on all four executors,
 and a pallas plan agrees numerically end-to-end."""
+import functools
 import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 from hypothesis import given, settings, strategies as st
 
 from repro.apps import lasso
@@ -19,7 +21,7 @@ from repro.kernels import (KERNEL_KINDS, KernelSpec, PallasKernels,
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.lasso_cd import DEFAULT_BLOCK_N, gram_block, lasso_partial
 from repro.kernels.moe_gating import topk_gating
-from repro.kernels import entry_sweep, split_chain
+from repro.kernels import entry_sweep, gibbs_scan, split_chain
 from repro.kernels.ssm_scan import ssm_scan
 
 R = np.random.default_rng(42)
@@ -354,6 +356,190 @@ def test_entry_sweep_off_the_tpu_is_xla():
                                   np.asarray(jax.ops.segment_sum(x, idx, n)))
     np.testing.assert_array_equal(np.asarray(b),
                                   np.asarray(jax.ops.segment_sum(y, idx, n)))
+
+
+# ---------------------------------------------------------------------------
+# LDA's Gibbs token loop (kernels/gibbs_scan.py)
+# ---------------------------------------------------------------------------
+
+def _repeats(r, T, rows, n, spread):
+    """``rows`` (T,) from ``n`` with the row groups of :data:`RING` + 2
+    pairs repeated at distances 1, 2, ..., RING + 1 apart (the tokens
+    between them in groups of their own), then a run of one row 40 long."""
+    rows = rows.copy()
+    groups = r.permutation(n // gibbs_scan.SUBLANES)
+    at, g = 5, 0
+    for d in range(1, gibbs_scan.RING + 2):
+        for i in range(d + 1):
+            rows[at + i] = groups[g % len(groups)] * gibbs_scan.SUBLANES \
+                + r.integers(spread)
+            g += 1
+        rows[at + d] = rows[at]
+        at += d + 3
+    rows[at:at + 40] = rows[at]
+    return rows
+
+
+#: name: (K, V, docs, T, workers, block, BLOCK, CHUNK)
+GIBBS_CASES = {
+    # K a multiple of neither 128 nor the lanes' tile; one block; T not a
+    # whole number of grid steps
+    "k64": (64, 600, 96, 700, 1, 0, 512, 8192),
+    # K = 200 (two lane tiles, the second part padding), a rotation's
+    # second block: block_start != 0, the first block's tokens unscheduled
+    "k200_second_block": (200, 600, 96, 700, 2, 1, 512, 8192),
+    # chunks of 256 in grid steps of 128: the chunk loop, a ring that
+    # does not carry across a chunk, a last chunk mostly padding
+    "chunks": (64, 300, 96, 600, 1, 0, 128, 256),
+}
+
+
+def _gibbs_case(name, monkeypatch):
+    from repro.apps import lda
+    K, V, dpw, T, W, block, blk, chunk = GIBBS_CASES[name]
+    monkeypatch.setattr(gibbs_scan, "BLOCK", blk)
+    monkeypatch.setattr(gibbs_scan, "CHUNK", chunk)
+    cfg = lda.LDAConfig(vocab=V, num_topics=K, num_workers=W,
+                        tokens_per_worker=T, docs_per_worker=dpw)
+    Vb = cfg.block_vocab
+    r = np.random.default_rng(sorted(GIBBS_CASES).index(name))
+    words = np.minimum(r.zipf(1.3, T) - 1, V - 1).astype(np.int32)
+    words = block * Vb + _repeats(r, T, words % Vb, Vb, 3)
+    docs = _repeats(r, T, r.integers(0, dpw, T), dpw, 8).astype(np.int32)
+    if W > 1:                            # the other block's tokens
+        other = r.random(T) < 0.3
+        words[other] = (words[other] + Vb) % cfg.padded_vocab
+    words[r.random(T) < 0.05] = -1
+    words = np.minimum(words, V - 1).astype(np.int32)
+    z = r.integers(0, min(K, 4), T).astype(np.int32)
+    B = np.zeros((cfg.padded_vocab, K), np.float32)
+    D = np.zeros((dpw, K), np.float32)
+    on = words >= 0
+    np.add.at(B, (words[on], z[on]), 1)
+    np.add.at(D, (docs[on], z[on]), 1)
+    s = B.sum(axis=0)
+    active = (words >= 0) & (words // Vb == block)
+    key = jax.random.fold_in(jax.random.key(17), block)
+    vloc = np.clip(words - block * Vb, 0, Vb - 1)
+    args = (jnp.asarray(B[block * Vb:(block + 1) * Vb]), jnp.asarray(D),
+            jnp.asarray(s), jnp.asarray(vloc), jnp.asarray(docs),
+            jnp.asarray(z), jnp.asarray(active))
+    return cfg, args, key
+
+
+def _gibbs_oracle(cfg, args, key):
+    from repro.apps import lda
+    keys = split_chain.subkeys(key, args[3].shape[0])
+    return jax.jit(functools.partial(
+        lda._gibbs_loop, cfg, impl=jax.random.key_impl(key)))(*args, keys)
+
+
+def _gibbs_kernel(cfg, args, key, interpret):
+    keys = jax.random.wrap_key_data(split_chain.subkeys(
+        key, args[3].shape[0]), impl=jax.random.key_impl(key))
+    return gibbs_scan.gibbs_scan_kernel(
+        *args, keys, alpha=cfg.alpha, gamma=cfg.gamma,
+        vgamma=cfg.padded_vocab * cfg.gamma, interpret=interpret)
+
+
+@pytest.mark.parametrize("name", sorted(GIBBS_CASES))
+def test_gibbs_kernel_is_the_loop_bit_for_bit(name, monkeypatch):
+    """The kernel (Pallas' TPU interpreter: a DMA lands when it is waited
+    for, unwritten memory reads NaN) draws the XLA loop's topics and
+    leaves its B, D and s̃, bit for bit: rows and documents repeated at
+    every distance up to past the ring, runs of one row, unscheduled
+    tokens and padding."""
+    cfg, args, key = _gibbs_case(name, monkeypatch)
+    B, D, s, z, fwd = _gibbs_kernel(
+        cfg, args, key, pltpu.InterpretParams())
+    want = _gibbs_oracle(cfg, args, key)
+    active = np.asarray(args[6])
+    np.testing.assert_array_equal(np.asarray(B), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(D), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(want[2]))
+    np.testing.assert_array_equal(np.asarray(z)[active],
+                                  np.asarray(want[3])[active])
+    # the draws moved topics, and the ring served reads at every distance
+    assert np.any(np.asarray(want[3]) != np.asarray(args[5]))
+    flags = np.asarray(gibbs_scan.token_table(*args[3:]))[:, 3]
+    for shift in (gibbs_scan._B_SHIFT, gibbs_scan._D_SHIFT):
+        back = (flags >> shift) & gibbs_scan._DIST_MASK
+        assert set(back[back > 0]) == set(range(1, gibbs_scan.RING))
+    assert int(fwd) == int(gibbs_scan.forwarded(args[3], args[4], args[6]))
+
+
+def test_gibbs_kernel_hazard_table_follows_the_ring(monkeypatch):
+    """A token forwards a row group last used fewer than RING tokens
+    before it (in its chunk, by an active token) and reads HBM otherwise;
+    a token writes its group back unless an active token within RING
+    after it forwards it."""
+    monkeypatch.setattr(gibbs_scan, "CHUNK", 512)
+    rows = np.array([0, 3, 16, 9, 0] + [64 + 8 * i for i in range(6)]
+                    + [16, 17] + [200] * 499, np.int32)
+    active = np.ones(rows.shape, bool)
+    active[3] = False                   # unscheduled: in nobody's chain
+    table = np.asarray(gibbs_scan.token_table(
+        jnp.asarray(rows), jnp.asarray(rows), jnp.zeros_like(rows),
+        jnp.asarray(active)))
+    assert table.shape == (512, 4)
+    flags = table[:, 3]
+    back = (flags >> gibbs_scan._B_SHIFT) & gibbs_scan._DIST_MASK
+    writes = flags & gibbs_scan._B_WRITE != 0
+    np.testing.assert_array_equal(back[:13],
+                                  [0, 1, 0, 0, 3, 0, 0, 0, 0, 0, 0, 0, 1])
+    assert (flags[:13] & gibbs_scan._ACTIVE != 0).tolist() == \
+        active[:13].tolist()
+    # 16 at token 2 and token 11 are 9 apart: both go through HBM
+    np.testing.assert_array_equal(writes[:13],
+                                  [0, 0, 1, 0, 1, 1, 1, 1, 1, 1, 1, 0, 1])
+    assert back[13:].tolist() == [0] + [1] * 498
+    assert not writes[13:-1].any() and writes[-1]
+
+
+def test_gibbs_noise_gives_categoricals_draws():
+    """``noise`` drawn for a batch of subkeys at once gives each token the
+    draw ``jax.random.categorical`` makes with its subkey."""
+    K, n = 200, 64
+    key = jax.random.key(5)
+    subs = jax.random.wrap_key_data(split_chain.subkeys(key, n))
+    logits = jnp.asarray(R.normal(size=(n, K)), jnp.float32)
+    eps = gibbs_scan.noise(subs, K)
+    assert eps.shape == (n, K) and eps.dtype == jnp.float32
+    want = jnp.stack([jax.random.categorical(subs[i], logits[i])
+                      for i in range(n)])
+    np.testing.assert_array_equal(np.asarray(jnp.argmax(eps + logits, 1)),
+                                  np.asarray(want))
+
+
+def test_gibbs_forwarded_count_is_the_lda_counters(monkeypatch):
+    """The kernel's own count of ring reads is what ``StradsLDA.obs_counts``
+    reports as ``forwarded`` for the same tokens."""
+    from repro.apps import lda
+    from repro.core import single_device_mesh
+    cfg, args, key = _gibbs_case("k64", monkeypatch)
+    fwd = _gibbs_kernel(cfg, args, key, True)[4]
+    eng = lda.make_engine(cfg, single_device_mesh())
+    words = np.where(np.asarray(args[6]), np.asarray(args[3]), -1)
+    data = {"words": jnp.asarray(words), "docs": args[4]}
+    counts = eng.app.obs_counts(data, None, 0)
+    assert len(counts) == 3
+    assert int(counts[2]) == int(fwd) > 0
+    assert int(counts[1]) == int(np.sum(np.asarray(args[6])))
+
+
+def test_gibbs_scan_off_the_tpu_is_the_loop():
+    """Off a TPU ``lda._gibbs_scan`` is the XLA loop: its compiled program
+    holds no Pallas kernel but the split chain's off-TPU scan."""
+    from repro.apps import lda
+    cfg = lda.LDAConfig(vocab=40, num_topics=8, num_workers=1,
+                        tokens_per_worker=64, docs_per_worker=4)
+    T, K = 64, 8
+    text = jax.jit(functools.partial(lda._gibbs_scan, cfg)).lower(
+        jnp.zeros((40, K)), jnp.zeros((4, K)), jnp.zeros((K,)),
+        jnp.zeros((T,), jnp.int32), jnp.zeros((T,), jnp.int32),
+        jnp.zeros((T,), jnp.int32), jnp.ones((T,), bool), jnp.int32(0),
+        jax.random.key(0)).as_text()
+    assert "tpu_custom_call" not in text and "gibbs_scan" not in text
 
 
 # ---------------------------------------------------------------------------

@@ -638,6 +638,30 @@ def test_lda_one_worker_samples_every_token_it_visits(mesh, rng):
         assert c["visited"] == c["updated"] == 6 * valid, executor
 
 
+def test_lda_counts_the_row_groups_its_gibbs_kernel_forwards(mesh, rng):
+    """``forwarded`` counts, each round, the B and D row-group reads the
+    Gibbs kernel's ring serves instead of HBM: read from the data and the
+    ring depth, so the CPU's XLA loop reports what the kernel would."""
+    from repro.kernels import gibbs_scan
+    cfg = lda.LDAConfig(vocab=30, num_topics=4, num_workers=1,
+                        tokens_per_worker=200, docs_per_worker=5)
+    words, docs, z0 = lda.synthetic_corpus(rng, cfg, true_topics=4)
+    words[::7] = -1
+    per_round = int(gibbs_scan.forwarded(
+        jnp.asarray(np.maximum(words, 0)), jnp.asarray(docs),
+        jnp.asarray(words >= 0)))
+    assert per_round > 0
+    eng = lda.make_engine(cfg, mesh)
+    data = eng.shard_data({"words": jnp.asarray(words),
+                           "docs": jnp.asarray(docs)})
+    state = eng.init_state(jax.random.key(0), words=words, docs=docs,
+                           z0=z0)
+    c = eng.execute(state, data, jax.random.key(1),
+                    _plan("scan", 3, TelemetrySpec(kind="counters"))
+                    ).telemetry.counters
+    assert c["forwarded"] == 3 * per_round
+
+
 def test_lda_four_workers_sample_a_quarter_of_what_they_visit():
     """Four forced host devices, one rotation (4 rounds): every worker
     scans all its tokens each round and samples those of its current
